@@ -53,6 +53,8 @@ def _fail(problems: List[str]) -> None:
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     fast = "--fast" in sys.argv
 
     def emit(name, us, derived=""):

@@ -359,7 +359,6 @@ def _sharded_cohort_fn(cfg, B_local, W, prog_len, msize, ops, mesh):
     (out-spec sharded), every other state leaf concatenates per-element
     along axis 0 — exactly the unsharded cohort layout for ``shards *
     B_local`` elements."""
-    from jax.experimental.shard_map import shard_map
     core = _build_core(cfg, B_local, W, prog_len, msize, ops)
     spec = _launch_spec(mesh, 1)
     row_spec = _launch_spec(mesh, 2)
@@ -368,14 +367,14 @@ def _sharded_cohort_fn(cfg, B_local, W, prog_len, msize, ops, mesh):
         st = core(prog, mem_rows[0], n_items, jnp.asarray(msize, jnp.int32))
         return st._replace(mem=st.mem[None])
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(jax.sharding.PartitionSpec(), row_spec,
                   jax.sharding.PartitionSpec()),
         out_specs=MachineState(pc=spec, regs=spec, done=spec, mem=row_spec,
                                tags=spec, cycles=spec, stats=spec,
                                step=spec),
-        check_rep=False)              # while_loop has no replication rule
+        check_vma=False)              # while_loop has no replication rule
     return jax.jit(fn, donate_argnums=(1,))
 
 
@@ -385,12 +384,11 @@ def _sharded_batch_fn(cfg, W, prog_len, msize, ops, mesh):
     axis is split across the mesh's data-parallel axes; each shard vmaps
     the single-launch core over its local launches and loops until only
     *they* halt."""
-    from jax.experimental.shard_map import shard_map
     core = _build_core(cfg, 1, W, prog_len, msize, ops)
     spec = _launch_spec(mesh, 1)
-    fn = shard_map(jax.vmap(core), mesh=mesh,
-                   in_specs=(spec, spec, spec, spec), out_specs=spec,
-                   check_rep=False)
+    fn = jax.shard_map(jax.vmap(core), mesh=mesh,
+                       in_specs=(spec, spec, spec, spec), out_specs=spec,
+                       check_vma=False)
     return jax.jit(fn, donate_argnums=(1,))
 
 
@@ -665,6 +663,11 @@ class LaunchHandle:
 
     def __len__(self) -> int:
         return self._B
+
+    def devices(self) -> set:
+        """The JAX devices holding this dispatch's final machine state —
+        where a pinned or sharded executor placed the work."""
+        return self._final.mem.devices()
 
     def ready(self) -> bool:
         """Non-blocking: has the device finished this dispatch?"""

@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 NEG_INF = -1e30
 
 
@@ -86,7 +84,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
     "causal", "window", "scale", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float = 0.0, block_q: int = 128,
-                    block_k: int = 512, interpret: bool = True):
+                    block_k: int = 512, interpret: bool = False):
     """q: (BH, Sq, hd); k, v: (BHkv, Skv, hd), BH = BHkv * G.
     Returns (BH, Sq, hd) in q's dtype. Sq/Skv are padded to block multiples
     internally; hd should be 128-aligned for MXU efficiency (any hd works
@@ -125,7 +123,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

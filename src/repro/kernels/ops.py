@@ -1,19 +1,17 @@
 """Jitted public wrappers for the Pallas kernels.
 
-Model code calls these; each dispatches to the Pallas kernel (interpret
-mode on CPU, compiled on TPU) and handles the model-side layout
-((B, S, H, hd) <-> the kernels' (BH, S, hd) folding).
+Model code calls these (the ``use_pallas=True`` paths); each compiles its
+Pallas kernel for the TPU — there is no interpreter fallback, so a caller
+on another backend fails instead of silently running the interpreter —
+and handles the model-side layout ((B, S, H, hd) <-> the kernels'
+(BH, S, hd) folding). Tests run the kernels in interpret mode by calling
+them directly with ``interpret=True``.
 """
 from __future__ import annotations
-
-import jax
-import jax.numpy as jnp
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import pe_simd as _pe
 from repro.kernels import rglru_scan as _rg
-
-_INTERPRET = jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -29,14 +27,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     kf = k.transpose(0, 2, 1, 3).reshape(bsz * hkv, skv, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(bsz * hkv, skv, hd)
     o = _fa.flash_attention(qf, kf, vf, causal=causal, window=window,
-                            scale=scale, interpret=_INTERPRET)
+                            scale=scale)
     return (o.reshape(bsz, hkv * g, sq, hd).transpose(0, 2, 1, 3))
 
 
 def rglru_scan(a, b, h0):
     """(B, S, D) recurrence; see rglru_scan.py."""
-    return _rg.rglru_scan(a, b, h0, interpret=_INTERPRET)
+    return _rg.rglru_scan(a, b, h0)
 
 
 def pe_execute(op, imm, a, b):
-    return _pe.pe_execute(op, imm, a, b, interpret=_INTERPRET)
+    return _pe.pe_execute(op, imm, a, b)

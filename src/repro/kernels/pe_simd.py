@@ -22,8 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
-
 from repro.ggpu.engine.alu import select_alu
 
 
@@ -36,7 +34,7 @@ def _pe_kernel(op_ref, imm_ref, a_ref, b_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_w", "interpret"))
-def pe_execute(op, imm, a, b, *, block_w: int = 8, interpret: bool = True):
+def pe_execute(op, imm, a, b, *, block_w: int = 8, interpret: bool = False):
     """op, imm: (W, 1) int32; a, b: (W, L) int32 -> (W, L) results.
     Grid tiles wavefronts; a block of 8 wavefronts x 64 lanes = one CU's
     PE array across 8 issue beats."""
@@ -60,7 +58,7 @@ def pe_execute(op, imm, a, b, *, block_w: int = 8, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((bw, l), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((wp, l), jnp.int32),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(op, imm, a, b)

@@ -2,12 +2,17 @@
 
 h_t = a_t * h_{t-1} + b_t, elementwise over the channel dim. The TPU
 adaptation: channels tile across the grid (VPU lanes, 128-aligned blocks);
-the sequence dim is walked in VMEM-resident chunks inside the kernel with
-the carried state h in scratch — HBM traffic is exactly one read of (a, b)
-and one write of h (the associative-scan jnp path re-materializes
+the sequence dim is walked as a sequential grid axis of chunks, with the
+carried state h in VMEM scratch — VMEM holds one (chunk, block_d) tile of
+each operand, never the whole sequence, and HBM traffic is exactly one read
+of (a, b) and one write of h (the associative-scan jnp path re-materializes
 log-depth intermediates instead).
 
-Grid: (B, D/block_d) parallel; S is looped inside the kernel body.
+Grid: (B, D/block_d, S/chunk) as ("parallel", "parallel", "arbitrary"):
+the chunk axis is innermost and sequential, so the carry in scratch flows
+from one chunk to the next. Rows are read and written through the refs
+(``ref[0, pl.ds(t, 1), :]``): Mosaic cannot index a loaded value with a
+traced ``t``.
 """
 from __future__ import annotations
 
@@ -18,34 +23,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import COMPILER_PARAMS as _COMPILER_PARAMS
 
+def _rglru_kernel(a_ref, b_ref, h0_ref, h_ref, hf_ref, carry, *, chunk: int):
+    k = pl.program_id(2)
 
-def _rglru_kernel(a_ref, b_ref, h0_ref, h_ref, hf_ref, carry, *, seq: int,
-                  chunk: int):
-    carry[...] = h0_ref[...].astype(jnp.float32)           # (1, bd)
-    n = seq // chunk
+    @pl.when(k == 0)
+    def _init():
+        carry[...] = h0_ref[0].astype(jnp.float32)         # (1, bd)
 
-    def step(i, _):
-        h = carry[...]
-        a = a_ref[0, pl.dslice(i * chunk, chunk), :].astype(jnp.float32)
-        b = b_ref[0, pl.dslice(i * chunk, chunk), :].astype(jnp.float32)
+    def step(t, h):
+        a = a_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        b = b_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        h = a * h + b
+        h_ref[0, pl.ds(t, 1), :] = h.astype(h_ref.dtype)
+        return h
 
-        def inner(t, hh):
-            hh = a[t][None, :] * hh + b[t][None, :]
-            h_ref[0, i * chunk + t, :] = hh[0].astype(h_ref.dtype)
-            return hh
-        h = jax.lax.fori_loop(0, chunk, inner, h)
-        carry[...] = h
-        return 0
+    carry[...] = jax.lax.fori_loop(0, chunk, step, carry[...])
 
-    jax.lax.fori_loop(0, n, step, 0)
-    hf_ref[...] = carry[...].astype(hf_ref.dtype)
+    @pl.when(k == pl.num_programs(2) - 1)
+    def _final():
+        hf_ref[0] = carry[...].astype(hf_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk", "interpret"))
 def rglru_scan(a, b, h0, *, block_d: int = 128, chunk: int = 128,
-               interpret: bool = True):
+               interpret: bool = False):
     """a, b: (B, S, D) f32; h0: (B, D) f32 -> (h (B,S,D), h_final (B,D))."""
     bsz, seq, d = a.shape
     bd = min(block_d, d)
@@ -64,26 +66,22 @@ def rglru_scan(a, b, h0, *, block_d: int = 128, chunk: int = 128,
     sp = seq + pad_s
     dp = d + pad_d
 
-    kernel = functools.partial(_rglru_kernel, seq=sp, chunk=ck)
+    # h0/h_final travel as (B, 1, D) so each block's last two dims are
+    # (1, bd): 1 equals the array's own dim, as the TPU tiling requires
+    seq_block = pl.BlockSpec((1, ck, bd), lambda i, j, k: (i, k, j))
+    state_block = pl.BlockSpec((1, 1, bd), lambda i, j, k: (i, 0, j))
     h, hf = pl.pallas_call(
-        kernel,
-        grid=(bsz, dp // bd),
-        in_specs=[
-            pl.BlockSpec((1, sp, bd), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, sp, bd), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, bd), lambda i, j: (i, j)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, sp, bd), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, bd), lambda i, j: (i, j)),
-        ],
+        functools.partial(_rglru_kernel, chunk=ck),
+        grid=(bsz, dp // bd, sp // ck),
+        in_specs=[seq_block, seq_block, state_block],
+        out_specs=[seq_block, state_block],
         out_shape=[
             jax.ShapeDtypeStruct((bsz, sp, dp), a.dtype),
-            jax.ShapeDtypeStruct((bsz, dp), a.dtype),
+            jax.ShapeDtypeStruct((bsz, 1, dp), a.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((1, bd), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
-            dimension_semantics=("parallel", "parallel")),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(a, b, h0)
-    return h[:, :seq, :d], hf[:, :d]
+    )(a, b, h0[:, None, :])
+    return h[:, :seq, :d], hf[:, 0, :d]

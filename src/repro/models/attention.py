@@ -5,7 +5,8 @@ Two execution paths:
     softmax) — O(S·chunk) memory, compiles on any backend; this is what the
     dry-run lowers. Used as the oracle for the Pallas kernel.
   * Pallas TPU flash kernel (``repro.kernels.flash_attention``) selected by
-    ``cfg.use_pallas`` — the TPU hot path, validated in interpret mode.
+    ``cfg.use_pallas`` — the TPU hot path; compiled only for a TPU, and
+    checked against the blocked path in interpret mode on the CPU.
 
 Shapes: q (B,S,H,hd); k,v (B,Skv,Hkv,hd); GQA folds H = Hkv * G.
 """

@@ -49,8 +49,10 @@ def test_flash_block_size_invariance():
     q = jax.random.normal(jax.random.PRNGKey(1), (2, 256, 64))
     k = jax.random.normal(jax.random.PRNGKey(2), (2, 256, 64))
     v = jax.random.normal(jax.random.PRNGKey(3), (2, 256, 64))
-    a = flash_attention(q, k, v, causal=True, block_q=32, block_k=32)
-    b = flash_attention(q, k, v, causal=True, block_q=128, block_k=256)
+    a = flash_attention(q, k, v, causal=True, block_q=32, block_k=32,
+                        interpret=True)
+    b = flash_attention(q, k, v, causal=True, block_q=128, block_k=256,
+                        interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
@@ -82,8 +84,10 @@ def test_rglru_composition_property(s, b):
     h0 = jax.random.normal(k3, (b, d))
     cut = max(1, s // 2)
     h_full, hf_full = ref.rglru_scan_ref(a, bb, h0)
-    _, hf1 = rglru_scan(a[:, :cut], bb[:, :cut], h0, chunk=8)
-    h2, hf2 = rglru_scan(a[:, cut:], bb[:, cut:], hf1, chunk=8)
+    _, hf1 = rglru_scan(a[:, :cut], bb[:, :cut], h0, chunk=8,
+                        interpret=True)
+    h2, hf2 = rglru_scan(a[:, cut:], bb[:, cut:], hf1, chunk=8,
+                         interpret=True)
     np.testing.assert_allclose(np.asarray(hf2), np.asarray(hf_full),
                                atol=1e-4)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h_full[:, cut:]),
