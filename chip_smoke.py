@@ -43,6 +43,7 @@ fallback.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import sys
 import time
@@ -169,34 +170,24 @@ def launch_pins(benches: dict, n: int, cfg) -> dict:
             for name, b in benches.items()}
 
 
-class CompileClock:
-    """Sums JAX's backend-compile durations (persistent-cache reads
-    included) and counts persistent-cache hits while active."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-
-    def _duration(self, event: str, duration: float, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.compiles += 1
-
-    def _event(self, event: str, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def __enter__(self):
-        import jax
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-        return self
-
-    def __exit__(self, *exc):
-        import jax
-        jax.monitoring.unregister_event_duration_listener(self._duration)
-        jax.monitoring.unregister_event_listener(self._event)
+@contextlib.contextmanager
+def compile_clock():
+    """Backend compiles (persistent-cache reads included), their seconds,
+    and the persistent-cache reads of what runs inside, as counted by
+    ``repro.tracing``'s compile counter; the dict it yields is filled with
+    the totals on exit."""
+    from repro import tracing
+    tracing.reset_counters()
+    tracing.enable()
+    totals: dict = {}
+    try:
+        yield totals
+    finally:
+        tracing.disable()
+        rows = tracing.counters().values()
+        totals.update(compile_s=sum(r["compile_s"] for r in rows),
+                      compiles=sum(r["compiles"] for r in rows),
+                      cache_hits=sum(r["cache_reads"] for r in rows))
 
 
 def _check_served(results, expect: dict, pins: dict, where) -> None:
@@ -229,7 +220,7 @@ def phase_a(benches: dict, pins: dict, *, launches: int = LAUNCHES,
     report = {"phase": "A", "benches": list(benches), "launches_served": 0,
               "memsys": {}}
     checks = []
-    with CompileClock() as clock:
+    with compile_clock() as clock:
         t_phase = time.perf_counter()
         for memsys in memsystems:
             cfg = GGPUConfig(n_cus=8, memsys=memsys)
@@ -273,8 +264,7 @@ def phase_a(benches: dict, pins: dict, *, launches: int = LAUNCHES,
         checks.append(f"fleet {placed}: every ticket back, exact vs "
                       "reference and pins, none quarantined")
         report["wall_s"] = time.perf_counter() - t_phase
-    report.update(compile_s=clock.seconds, compiles=clock.compiles,
-                  cache_hits=clock.cache_hits, checks=checks)
+    report.update(clock, checks=checks)
     return report
 
 
@@ -301,7 +291,7 @@ def phase_b(cfg, *, prompt_lens=PROMPT_LENS, max_new: int = MAX_NEW
     lens = rng.integers(prompt_lens[0], prompt_lens[1] + 1, N_PROMPTS)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in lens]
     checks = []
-    with CompileClock() as clock:
+    with compile_clock() as clock:
         t_phase = time.perf_counter()
         params = init_params(cfg, jax.random.PRNGKey(SEED))
         engine = Engine(flash, params, EngineConfig(slots=N_PROMPTS))
@@ -362,8 +352,7 @@ def phase_b(cfg, *, prompt_lens=PROMPT_LENS, max_new: int = MAX_NEW
             "bf16_flash_vs_f32_max_diff": served_err,
             "bf16_jnp_vs_f32_max_diff": plain_err,
             "bf16_flash_vs_jnp_max_diff": bf16_diff,
-            "compile_s": clock.seconds, "compiles": clock.compiles,
-            "cache_hits": clock.cache_hits, "checks": checks}
+            **clock, "checks": checks}
 
 
 def phase_sharded(bench, devices, fleet_benches: dict, *,
@@ -379,7 +368,7 @@ def phase_sharded(bench, devices, fleet_benches: dict, *,
     cfg = GGPUConfig(n_cus=8)
     imgs = images(bench, n)
     checks = []
-    with CompileClock() as clock:
+    with compile_clock() as clock:
         t_phase = time.perf_counter()
         served = {}
         sharded = Scheduler(cfg, mesh=mesh)
@@ -449,8 +438,7 @@ def phase_sharded(bench, devices, fleet_benches: dict, *,
             "chip0_wall_s": served["chip0"]["wall_s"],
             "fleet_wall_s": fleet_wall, "fleet_placement": placed,
             "launches_served": 2 * n + len(results), "wall_s": wall,
-            "compile_s": clock.seconds, "compiles": clock.compiles,
-            "cache_hits": clock.cache_hits, "checks": checks}
+            **clock, "checks": checks}
 
 
 def _emit(report: dict) -> None:

@@ -178,18 +178,26 @@ def _build_core(cfg: GGPUConfig, B: int, W: int, prog_len: int, msize: int,
             # post-halt (or past-max_steps) round an exact no-op for that
             # element — no per-round control flow needed, which keeps fused
             # sub-rounds branch-free while step/cycle accounting stays
-            # identical to one-round-per-iteration dispatch
-            runvec = (~jnp.all(s.done.reshape(B, -1), axis=1)) \
-                & (s.step < cfg.max_steps)                      # (B,)
-            active, _ = scheduler.select_resident(
-                s.done, n_cus=n_cus, max_wf_per_cu=cfg.max_wf_per_cu,
-                n_elems=B, force_rank=legacy)
-            active = active & jnp.repeat(runvec, W)[:, None]
-            f = frontend.fetch_decode(prog, prog_len, s.pc, active, s.regs)
-            res = alu.select_alu(f.op, f.a, f.b, f.imm, ops_present)
-            res = frontend.apply_intrinsics(res, f.op, gid, n_items, L,
-                                            ops_present)
+            # identical to one-round-per-iteration dispatch. The stages
+            # carry named scopes (schedule, frontend, alu, memsys, cost)
+            # in their ops' metadata, so a trace can split the round
+            with jax.named_scope("schedule"):
+                runvec = (~jnp.all(s.done.reshape(B, -1), axis=1)) \
+                    & (s.step < cfg.max_steps)                  # (B,)
+                active, _ = scheduler.select_resident(
+                    s.done, n_cus=n_cus, max_wf_per_cu=cfg.max_wf_per_cu,
+                    n_elems=B, force_rank=legacy)
+                active = active & jnp.repeat(runvec, W)[:, None]
+            with jax.named_scope("frontend"):
+                f = frontend.fetch_decode(prog, prog_len, s.pc, active,
+                                          s.regs)
+            with jax.named_scope("alu"):
+                res = alu.select_alu(f.op, f.a, f.b, f.imm, ops_present)
+            with jax.named_scope("frontend"):
+                res = frontend.apply_intrinsics(res, f.op, gid, n_items, L,
+                                                ops_present)
 
+            @jax.named_scope("memsys")
             def mem_round(res):
                 addr_local = jnp.clip(f.a + f.imm, 0, msize_clip - 1)
                 is_load = f.op == isa.LW
@@ -225,28 +233,34 @@ def _build_core(cfg: GGPUConfig, B: int, W: int, prog_len: int, msize: int,
                 out = mem_round(res)
             res, mem, tags, hit_service, fill, n_mem, n_hit, n_miss = out
 
-            regs = frontend.writeback(s.regs, f, res, is_branch,
-                                      dense=legacy)
-            taken = alu.branch_taken(f.op, f.a, f.b, ops_present) & f.exec_m
-            pc, done = frontend.advance(s.pc, s.done, f, taken)
-            if cfg.pipeline_depth > 0:
-                # pipeline-latency feedback: each planner-inserted stage adds
-                # one un-bypassed dependency bubble per issuing wavefront and
-                # one refill cycle when the wavefront takes a branch
-                pipe_stall = cfg.pipeline_depth * (
-                    jnp.any(f.exec_m, axis=1).astype(jnp.int32)
-                    + jnp.any(taken, axis=1).astype(jnp.int32))
-            else:
-                pipe_stall = None
-            round_t, wf_exec = scheduler.round_cost(
-                f.op[:, 0], f.exec_m, extra=extra,
-                issue_cycles=cfg.issue_cycles, cu_of_w=cu_of_w,
-                n_cus=n_cus, n_elems=B, hit_service=hit_service,
-                fill_cycles=fill, use_scatter=legacy,
-                pipe_stall=pipe_stall)
-            cycles = s.cycles + round_t.astype(jnp.int32)
-            stats = s.stats + jnp.stack(
-                [per_elem_sum(wf_exec), n_mem, n_hit, n_miss], axis=1)
+            with jax.named_scope("frontend"):
+                regs = frontend.writeback(s.regs, f, res, is_branch,
+                                          dense=legacy)
+            with jax.named_scope("alu"):
+                taken = alu.branch_taken(f.op, f.a, f.b, ops_present) \
+                    & f.exec_m
+            with jax.named_scope("frontend"):
+                pc, done = frontend.advance(s.pc, s.done, f, taken)
+            with jax.named_scope("cost"):
+                if cfg.pipeline_depth > 0:
+                    # pipeline-latency feedback: each planner-inserted
+                    # stage adds one un-bypassed dependency bubble per
+                    # issuing wavefront and one refill cycle when the
+                    # wavefront takes a branch
+                    pipe_stall = cfg.pipeline_depth * (
+                        jnp.any(f.exec_m, axis=1).astype(jnp.int32)
+                        + jnp.any(taken, axis=1).astype(jnp.int32))
+                else:
+                    pipe_stall = None
+                round_t, wf_exec = scheduler.round_cost(
+                    f.op[:, 0], f.exec_m, extra=extra,
+                    issue_cycles=cfg.issue_cycles, cu_of_w=cu_of_w,
+                    n_cus=n_cus, n_elems=B, hit_service=hit_service,
+                    fill_cycles=fill, use_scatter=legacy,
+                    pipe_stall=pipe_stall)
+                cycles = s.cycles + round_t.astype(jnp.int32)
+                stats = s.stats + jnp.stack(
+                    [per_elem_sum(wf_exec), n_mem, n_hit, n_miss], axis=1)
             return MachineState(pc, regs, done, mem, tags, cycles, stats,
                                 s.step + runvec.astype(jnp.int32))
 

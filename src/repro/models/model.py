@@ -70,35 +70,43 @@ def init_cache(cfg: ModelConfig, batch: int, cap: int):
 
 def _apply_unit(unit, p_unit, x, cfg: ModelConfig, caches, positions,
                 cache_pos, mode: str, prefill_pad: int = 0):
-    """Apply the blocks of one pattern unit. Returns (x, new_caches, aux)."""
+    """Apply the blocks of one pattern unit. Returns (x, new_caches, aux).
+
+    Named scopes (op-name metadata only): ``attn`` around an attention
+    mixer, its KV-cache slice and update included; the recurrent kind's
+    name around a recurrent mixer; ``mlp`` around the MLP or MoE."""
     aux = jnp.zeros((), jnp.float32)
     new_caches: Dict[str, Any] = {}
     for idx, kind in enumerate(unit):
         bp = p_unit[str(idx)]
         ci = caches.get(str(idx)) if caches is not None else None
-        if kind in ("attn", "swa", "local"):
-            out, c_new = attn_block(bp["mixer"], x, cfg, kind,
-                                    positions=positions, cache=ci,
-                                    cache_pos=cache_pos)
-            if mode == "train":
-                c_new = None
-            elif mode == "prefill":
-                c_new = _prefill_attn_cache(cfg, kind, c_new, prefill_pad)
-        elif kind == "mlstm":
-            out, c_new = rec.mlstm_block(bp["mixer"], x, cfg, ci)
-        elif kind == "slstm":
-            out, c_new = rec.slstm_block(bp["mixer"], x, cfg, ci)
-        elif kind == "rglru":
-            out, c_new = rec.rglru_block(bp["mixer"], x, cfg, ci)
-        else:
-            raise ValueError(kind)
+        attention = kind in ("attn", "swa", "local")
+        with jax.named_scope("attn" if attention else kind):
+            if attention:
+                out, c_new = attn_block(bp["mixer"], x, cfg, kind,
+                                        positions=positions, cache=ci,
+                                        cache_pos=cache_pos)
+                if mode == "train":
+                    c_new = None
+                elif mode == "prefill":
+                    c_new = _prefill_attn_cache(cfg, kind, c_new,
+                                                prefill_pad)
+            elif kind == "mlstm":
+                out, c_new = rec.mlstm_block(bp["mixer"], x, cfg, ci)
+            elif kind == "slstm":
+                out, c_new = rec.slstm_block(bp["mixer"], x, cfg, ci)
+            elif kind == "rglru":
+                out, c_new = rec.rglru_block(bp["mixer"], x, cfg, ci)
+            else:
+                raise ValueError(kind)
         x = shard_hint(x + out, "acts")
         if "mlp" in bp:
-            if cfg.n_experts:
-                mo, a = apply_moe(bp["mlp"], x, cfg)
-                aux = aux + a
-            else:
-                mo = apply_mlp(bp["mlp"], x, cfg)
+            with jax.named_scope("mlp"):
+                if cfg.n_experts:
+                    mo, a = apply_moe(bp["mlp"], x, cfg)
+                    aux = aux + a
+                else:
+                    mo = apply_mlp(bp["mlp"], x, cfg)
             x = shard_hint(x + mo, "acts")
         if c_new is not None:
             new_caches[str(idx)] = c_new
@@ -262,7 +270,8 @@ def chunked_lm_loss(params, cfg: ModelConfig, x, labels, chunk: int = 1024):
 
 
 def lm_logits(params, cfg: ModelConfig, x):
-    return shard_hint(unembed(params, x, cfg), "logits")
+    with jax.named_scope("lm_head"):
+        return shard_hint(unembed(params, x, cfg), "logits")
 
 
 # ---------------------------------------------------------------------------

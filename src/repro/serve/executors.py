@@ -49,11 +49,13 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import itertools
 import time
 from typing import Dict, List, Optional, Sequence
 
 import jax
 
+from repro import tracing
 from repro.ggpu.engine import (BlockPatch, GGPUConfig, KernelLaunchError,
                                LaunchHandle, XorBlockPatch,
                                cohort_rows, launch_shards)
@@ -122,13 +124,18 @@ def sim_key(cfg: GGPUConfig) -> GGPUConfig:
 class PendingChunk:
     """One dispatched chunk in flight on the device, awaiting collection.
     ``t_dispatch`` is the wall clock at dispatch — the reference point for
-    executor timeouts and fleet-level hedging."""
+    executor timeouts and fleet-level hedging. ``seq`` numbers dispatches
+    in this process; the executor's program spans carry it as ``chunk``."""
     handle: LaunchHandle
     kind: str
     reqs: List[Request]
     env: tuple
     traced: bool
     t_dispatch: float = 0.0
+    seq: int = 0
+
+
+_DISPATCH_SEQ = itertools.count()
 
 
 class Executor:
@@ -204,8 +211,14 @@ class Executor:
         device arrays before dispatch — a ``repro.ggpu.engine.BlockPatch``
         or one ``[(lo, hi, src), ...]`` list per launch — the
         device-resident chaining path a dependency-aware scheduler uses to
-        feed a producer's output into a consumer with no host transfer."""
+        feed a producer's output into a consumer with no host transfer.
+        The work runs in program span ``executor.stage``."""
         reqs = list(reqs)
+        seq = next(_DISPATCH_SEQ)
+        with tracing.span("executor.stage", chunk=seq, launches=len(reqs)):
+            return self._submit(kind, reqs, patches, seq)
+
+    def _submit(self, kind, reqs, patches, seq) -> PendingChunk:
         if len(reqs) == 1:
             kind = "single"          # a degenerate chunk needs no folding
         env = self._envelope(kind, reqs)
@@ -247,7 +260,7 @@ class Executor:
                     out_region=regions[0] if regions else None,
                     patches=single)
         return PendingChunk(h, kind, reqs, env, traced,
-                            t_dispatch=time.monotonic())
+                            t_dispatch=time.monotonic(), seq=seq)
 
     def chunk_ready(self, pending: PendingChunk) -> bool:
         """Non-blocking: has the device finished this chunk? (The hook a
@@ -263,17 +276,23 @@ class Executor:
         dispatches (a failed chunk is retried with fewer members, a
         different envelope). With ``timeout_s`` set, a chunk still
         unresolved ``timeout_s`` after its dispatch raises
-        ``DeviceTimeout`` (``index=None``: the whole chunk is suspect)."""
-        if self.timeout_s is not None:
-            deadline = pending.t_dispatch + self.timeout_s
-            while not self.chunk_ready(pending):
-                now = time.monotonic()
-                if now >= deadline:
-                    raise DeviceTimeout(
-                        f"chunk of {len(pending.reqs)} launch(es) not "
-                        f"resolved within {self.timeout_s}s of dispatch")
-                time.sleep(min(1e-3, deadline - now))
-        outs = pending.handle.results()
+        ``DeviceTimeout`` (``index=None``: the whole chunk is suspect).
+        Program spans: ``executor.wait`` while the device finishes the
+        chunk, ``executor.download`` while its results come back."""
+        ids = {"chunk": pending.seq, "launches": len(pending.reqs)}
+        with tracing.span("executor.wait", **ids):
+            if self.timeout_s is not None:
+                deadline = pending.t_dispatch + self.timeout_s
+                while not self.chunk_ready(pending):
+                    now = time.monotonic()
+                    if now >= deadline:
+                        raise DeviceTimeout(
+                            f"chunk of {len(pending.reqs)} launch(es) not "
+                            f"resolved within {self.timeout_s}s of dispatch")
+                    time.sleep(min(1e-3, deadline - now))
+            pending.handle.wait()
+        with tracing.span("executor.download", **ids):
+            outs = pending.handle.results()
         if pending.traced:
             self.stats.trace_hits += 1
         else:

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.models import model as M
 from repro.models.config import ModelConfig
 from repro.models.steps import make_decode_step
@@ -47,40 +48,59 @@ class Engine:
     def generate(self, prompts: List[List[int]], max_new: int
                  ) -> List[List[int]]:
         """Slot-batched generation. Prompts are queued; each batch wave
-        prefills up to ``slots`` prompts padded to a common length."""
+        prefills up to ``slots`` prompts padded to a common length.
+
+        Program spans (``repro.tracing``): ``engine.generate`` around the
+        call, ``engine.wave`` around each slot wave, and inside it
+        ``engine.prefill``, ``engine.decode`` (each decode dispatch),
+        ``engine.sample`` and ``engine.token_pull`` (each loop of
+        ``int(last[r])``, where the host waits for the device)."""
+        with tracing.span("engine.generate"):
+            return self._generate(prompts, max_new)
+
+    def _generate(self, prompts, max_new):
         ecfg = self.ecfg
         results: List[Optional[List[int]]] = [None] * len(prompts)
         rng = jax.random.PRNGKey(ecfg.seed)
-        for wave in plan_waves(range(len(prompts)), ecfg.slots):
-            plen = max(len(prompts[i]) for i in wave)
-            batch = np.zeros((len(wave), plen), np.int32)
-            for r, i in enumerate(wave):
-                batch[r, plen - len(prompts[i]):] = prompts[i]  # left-pad
-            cap = plen + max_new + 1
-            logits, cache = M.prefill(self.params, self.cfg,
-                                      tokens=jnp.asarray(batch), pad_to=cap)
-            toks = [list(prompts[i]) for i in wave]
-            last = self._sample(logits, rng)
-            done = np.zeros(len(wave), bool)
-            for r in range(len(wave)):
-                tok = int(last[r])
-                toks[r].append(tok)
-                if tok == ecfg.eos_id:
-                    done[r] = True       # EOS straight out of prefill
-            for t in range(max_new - 1):
-                if done.all():
-                    break
-                rng, sub = jax.random.split(rng)
-                logits, cache = self.decode_fn(
-                    self.params, cache, last[:, None],
-                    jnp.asarray(plen + t, jnp.int32))
-                last = self._sample(logits, sub)
-                for r in range(len(wave)):
-                    if not done[r]:
+        for w, wave in enumerate(plan_waves(range(len(prompts)),
+                                            ecfg.slots)):
+            with tracing.span("engine.wave", wave=w):
+                plen = max(len(prompts[i]) for i in wave)
+                batch = np.zeros((len(wave), plen), np.int32)
+                for r, i in enumerate(wave):
+                    batch[r, plen - len(prompts[i]):] = prompts[i]  # left-pad
+                cap = plen + max_new + 1
+                with tracing.span("engine.prefill"):
+                    logits, cache = M.prefill(self.params, self.cfg,
+                                              tokens=jnp.asarray(batch),
+                                              pad_to=cap)
+                toks = [list(prompts[i]) for i in wave]
+                with tracing.span("engine.sample"):
+                    last = self._sample(logits, rng)
+                done = np.zeros(len(wave), bool)
+                with tracing.span("engine.token_pull"):
+                    for r in range(len(wave)):
                         tok = int(last[r])
                         toks[r].append(tok)
                         if tok == ecfg.eos_id:
-                            done[r] = True
-            for r, i in enumerate(wave):
-                results[i] = toks[r]
+                            done[r] = True       # EOS straight out of prefill
+                for t in range(max_new - 1):
+                    if done.all():
+                        break
+                    rng, sub = jax.random.split(rng)
+                    with tracing.span("engine.decode"):
+                        logits, cache = self.decode_fn(
+                            self.params, cache, last[:, None],
+                            jnp.asarray(plen + t, jnp.int32))
+                    with tracing.span("engine.sample"):
+                        last = self._sample(logits, sub)
+                    with tracing.span("engine.token_pull"):
+                        for r in range(len(wave)):
+                            if not done[r]:
+                                tok = int(last[r])
+                                toks[r].append(tok)
+                                if tok == ecfg.eos_id:
+                                    done[r] = True
+                for r, i in enumerate(wave):
+                    results[i] = toks[r]
         return results  # type: ignore

@@ -62,6 +62,7 @@ from typing import Deque, Dict, List, Optional, Sequence, Tuple
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.ggpu.engine import BlockPatch, GGPUConfig, KernelLaunchError
 from repro.registry import SCHEDULERS
 from repro.serve.executors import Executor, PendingChunk
@@ -364,8 +365,9 @@ class Scheduler:
         barrier in between."""
         taken = 0
         while budget is None or taken < budget:
-            items = self._ready()
-            chunks = self._plan(items, self.cfg, self.plan_batch)
+            with tracing.span("scheduler.plan"):
+                items = self._ready()
+                chunks = self._plan(items, self.cfg, self.plan_batch)
             progress = False
             for chunk in chunks:
                 if budget is not None and taken >= budget:
