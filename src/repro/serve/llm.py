@@ -50,11 +50,21 @@ class Engine:
         """Slot-batched generation. Prompts are queued; each batch wave
         prefills up to ``slots`` prompts padded to a common length.
 
+        Tokens come back one step behind: decode step ``t+1`` and its
+        sampling are dispatched before the host pulls the ``(B,)`` tokens
+        of step ``t`` in one transfer, so the pull waits on a device that
+        already has the next step queued. EOS book-keeping runs on the
+        host one step late; when every row is done, the one step dispatched
+        past that is dropped, and its rng split is not kept, so the tokens
+        are those of a step-by-step loop.
+
         Program spans (``repro.tracing``): ``engine.generate`` around the
         call, ``engine.wave`` around each slot wave, and inside it
         ``engine.prefill``, ``engine.decode`` (each decode dispatch),
-        ``engine.sample`` and ``engine.token_pull`` (each loop of
-        ``int(last[r])``, where the host waits for the device)."""
+        ``engine.sample`` and ``engine.token_pull`` (the blocking transfer
+        of one step's tokens, where the host waits for the device). A pull
+        carries ``ahead=``, the decode steps dispatched and not yet pulled
+        when it began: 1 inside the loop, 0 for a wave's last pull."""
         with tracing.span("engine.generate"):
             return self._generate(prompts, max_new)
 
@@ -75,32 +85,34 @@ class Engine:
                                               tokens=jnp.asarray(batch),
                                               pad_to=cap)
                 toks = [list(prompts[i]) for i in wave]
+                done = np.zeros(len(wave), bool)
+
+                def pull(last, ahead):
+                    """Append one step's tokens to the rows not yet done;
+                    True once every row is."""
+                    with tracing.span("engine.token_pull", ahead=ahead):
+                        got = jax.device_get(last).tolist()
+                    for r, tok in enumerate(got):
+                        if not done[r]:
+                            toks[r].append(tok)
+                            done[r] = tok == ecfg.eos_id
+                    return done.all()
+
                 with tracing.span("engine.sample"):
                     last = self._sample(logits, rng)
-                done = np.zeros(len(wave), bool)
-                with tracing.span("engine.token_pull"):
-                    for r in range(len(wave)):
-                        tok = int(last[r])
-                        toks[r].append(tok)
-                        if tok == ecfg.eos_id:
-                            done[r] = True       # EOS straight out of prefill
                 for t in range(max_new - 1):
-                    if done.all():
-                        break
-                    rng, sub = jax.random.split(rng)
+                    nrng, sub = jax.random.split(rng)
                     with tracing.span("engine.decode"):
                         logits, cache = self.decode_fn(
                             self.params, cache, last[:, None],
                             jnp.asarray(plen + t, jnp.int32))
                     with tracing.span("engine.sample"):
-                        last = self._sample(logits, sub)
-                    with tracing.span("engine.token_pull"):
-                        for r in range(len(wave)):
-                            if not done[r]:
-                                tok = int(last[r])
-                                toks[r].append(tok)
-                                if tok == ecfg.eos_id:
-                                    done[r] = True
+                        nxt = self._sample(logits, sub)
+                    if pull(last, ahead=1):
+                        break                    # step t is dropped
+                    rng, last = nrng, nxt
+                else:
+                    pull(last, ahead=0)
                 for r, i in enumerate(wave):
                     results[i] = toks[r]
         return results  # type: ignore

@@ -371,3 +371,114 @@ def test_engine_prefill_eos_regression():
                  EngineConfig(slots=1, temperature=0.0, eos_id=int(first))) \
         .generate([[1, 2]], max_new=6)[0]
     assert out == [1, 2, int(first)]
+
+
+# Prompts of uneven lengths: two waves of a 3-slot engine, so the rng a
+# wave leaves behind feeds the next one's sampling.
+ID_PROMPTS = [[1, 2, 3], [4, 5], [6, 7, 8, 9, 10], [11], [12, 13, 14, 15]]
+
+
+@pytest.fixture(scope="module")
+def stepwise():
+    """``(engine, free)`` for ``(slots, temperature, max_new)``: one engine
+    per slots and temperature, and the step-by-step loop's tokens with no
+    EOS, both shared by the cases that need them."""
+    import jax
+
+    from repro.configs import get_smoke
+    from repro.models.schema import init_params
+    from repro.serve import Engine, EngineConfig
+    cfg = get_smoke("qwen1.5-0.5b")
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    engines, frees = {}, {}
+
+    def get(slots, temperature, max_new):
+        key = (slots, temperature)
+        if key not in engines:
+            engines[key] = Engine(cfg, params, EngineConfig(
+                slots=slots, temperature=temperature, seed=5))
+        engine = engines[key]
+        engine.ecfg.eos_id = -1
+        if key + (max_new,) not in frees:
+            frees[key + (max_new,)] = _stepwise_generate(engine, ID_PROMPTS,
+                                                         max_new)
+        return engine, frees[key + (max_new,)]
+    return get
+
+
+def _stepwise_generate(engine, prompts, max_new):
+    """The engine's algorithm one step at a time: each decode step is
+    dispatched only after the host has read the last step's tokens, one
+    ``int()`` per row, and EOS is checked before every step."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import model as M
+    ecfg = engine.ecfg
+    results = [None] * len(prompts)
+    rng = jax.random.PRNGKey(ecfg.seed)
+    for wave in plan_waves(range(len(prompts)), ecfg.slots):
+        plen = max(len(prompts[i]) for i in wave)
+        batch = np.zeros((len(wave), plen), np.int32)
+        for r, i in enumerate(wave):
+            batch[r, plen - len(prompts[i]):] = prompts[i]
+        logits, cache = M.prefill(engine.params, engine.cfg,
+                                  tokens=jnp.asarray(batch),
+                                  pad_to=plen + max_new + 1)
+        toks = [list(prompts[i]) for i in wave]
+        last = engine._sample(logits, rng)
+        done = np.zeros(len(wave), bool)
+        for r in range(len(wave)):
+            toks[r].append(int(last[r]))
+            done[r] = toks[r][-1] == ecfg.eos_id
+        for t in range(max_new - 1):
+            if done.all():
+                break
+            rng, sub = jax.random.split(rng)
+            logits, cache = engine.decode_fn(
+                engine.params, cache, last[:, None],
+                jnp.asarray(plen + t, jnp.int32))
+            last = engine._sample(logits, sub)
+            for r in range(len(wave)):
+                if not done[r]:
+                    toks[r].append(int(last[r]))
+                    done[r] = toks[r][-1] == ecfg.eos_id
+        for r, i in enumerate(wave):
+            results[i] = toks[r]
+    return results
+
+
+def _staggered_eos(outs, prompts):
+    """The new token first reached at the most distinct steps across
+    requests."""
+    firsts = {}
+    for p, o in zip(prompts, outs):
+        new = o[len(p):]
+        for tok in set(new):
+            firsts.setdefault(tok, set()).add(new.index(tok))
+    return max(sorted(firsts), key=lambda tok: len(firsts[tok]))
+
+
+@pytest.mark.parametrize("slots,temperature,max_new,eos", [
+    (3, temp, n, eos) for temp in (0.0, 0.7) for n in (1, 2, 8)
+    for eos in ("none", "prefill", "staggered")] + [
+    (1, temp, 8, "staggered") for temp in (0.0, 0.7)])
+def test_engine_matches_stepwise_generation(stepwise, slots, temperature,
+                                            max_new, eos):
+    """The one-step-behind loop returns the tokens of the step-by-step
+    loop: greedy and sampled, EOS out of prefill and EOS at different steps
+    per row (with one slot, whole waves end early and the next wave samples
+    from the rng the early one left)."""
+    engine, free = stepwise(slots, temperature, max_new)
+    assert all(len(o) - len(p) == max_new
+               for p, o in zip(ID_PROMPTS, free))
+    want = free
+    if eos != "none":
+        engine.ecfg.eos_id = (free[0][len(ID_PROMPTS[0])] if eos == "prefill"
+                              else _staggered_eos(free, ID_PROMPTS))
+        want = _stepwise_generate(engine, ID_PROMPTS, max_new)
+    if eos == "staggered" and max_new == 8:
+        stops = {len(o) - len(p) for p, o in zip(ID_PROMPTS, want)
+                 if o[-1] == engine.ecfg.eos_id}
+        assert len(stops) >= 2 and min(stops) < max_new
+    assert engine.generate(ID_PROMPTS, max_new) == want

@@ -132,13 +132,21 @@ def test_cohort_stepper_carries_the_stage_scopes_and_keeps_its_name():
         <= _scopes(lowered)
 
 
-def test_engine_spans_one_wave(tmp_path, tracer):
+def test_engine_spans_one_wave(tmp_path, tracer, monkeypatch):
     from repro.serve import Engine, EngineConfig
     cfg, params = _smoke_model()
     engine = Engine(cfg, params, EngineConfig(slots=2))
     max_new = 4
+    pulled = []
+    device_get = jax.device_get
+
+    def counting(x):
+        pulled.append(np.shape(x))
+        return device_get(x)
+    monkeypatch.setattr(jax, "device_get", counting)
     events = _program(_profiled(
         tmp_path, lambda: engine.generate([[1, 2, 3], [4, 5]], max_new)))
+    assert pulled == [(2,)] * max_new        # one whole-vector pull a step
     names = [e[0][len(tracing.PREFIX):] for e in events]
     assert names.count("engine.generate") == 1
     assert names.count("engine.wave") == 1
@@ -146,6 +154,11 @@ def test_engine_spans_one_wave(tmp_path, tracer):
     assert names.count("engine.decode") == max_new - 1
     assert names.count("engine.sample") == max_new
     assert names.count("engine.token_pull") == max_new
+    # each pull but the last has the next decode step queued behind it
+    pulls = sorted((e for e in events if e[0] == "repro.engine.token_pull"),
+                   key=lambda e: e[1])
+    assert [p[3] for p in pulls] \
+        == [{"ahead": 1}] * (max_new - 1) + [{"ahead": 0}]
     wave, = [e for e in events if e[0] == "repro.engine.wave"]
     assert wave[3] == {"wave": 0}
     assert all(wave[1] <= e[1] and e[2] <= wave[2] for e in events
@@ -154,6 +167,30 @@ def test_engine_spans_one_wave(tmp_path, tracer):
     rows = tracing.counters()
     assert rows["engine.prefill"]["compiles"] >= 1
     assert rows["engine.decode"]["compiles"] >= 1
+
+
+def test_engine_dispatches_one_step_past_a_wave_done(tmp_path, tracer):
+    """Every row reaches EOS at its k-th new token: the step-by-step loop
+    would dispatch k - 1 decode steps; the engine dispatches one more and
+    drops its tokens."""
+    from repro.serve import Engine, EngineConfig
+    cfg, params = _smoke_model()
+    prompts = [[7, 8, 9]] * 2                 # rows that agree, greedily
+    max_new = 6
+    free = Engine(cfg, params, EngineConfig(slots=2)).generate(prompts,
+                                                               max_new)
+    new = free[0][3:]
+    assert free[1] == free[0]
+    k = next(j for j in range(2, max_new) if new[j - 1] not in new[:j - 1])
+    engine = Engine(cfg, params, EngineConfig(slots=2, eos_id=new[k - 1]))
+    out = []
+    events = _program(_profiled(
+        tmp_path, lambda: out.extend(engine.generate(prompts, max_new))))
+    assert out == [free[0][:3 + k]] * 2
+    names = [e[0][len(tracing.PREFIX):] for e in events]
+    assert names.count("engine.decode") == k <= max_new - 1
+    assert names.count("engine.token_pull") == k
+    assert names.count("engine.sample") == k + 1
 
 
 def test_scheduler_and_executor_spans_share_the_chunk_id(tmp_path, tracer):
