@@ -18,6 +18,7 @@ _MODULES = {
     "recurrentgemma-2b": "recurrentgemma_2b",
     "hubert-xlarge": "hubert_xlarge",
     "qwen2-vl-72b": "qwen2_vl_72b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 ARCH_IDS = tuple(_MODULES)

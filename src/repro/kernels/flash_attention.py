@@ -6,8 +6,9 @@ is the "monolithic memory" — it never touches HBM. The grid tiles
 BlockSpecs, scores/softmax state live in VMEM scratch, and the MXU sees
 (block_q x hd) @ (hd x block_k) matmuls with 128-aligned tiles.
 
-Supports causal, sliding-window and bidirectional masking, and GQA (the
-kv BlockSpec index map folds the query-head group onto its kv head).
+Supports causal, sliding-window and bidirectional masking, GQA (the
+kv BlockSpec index map folds the query-head group onto its kv head), and
+values narrower than queries and keys (MLA: 128 beside 192).
 
 Grid semantics: ("parallel", "parallel", "arbitrary") — the kv dimension is
 innermost and sequential, so the scratch accumulators carry across kv steps
@@ -85,12 +86,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float = 0.0, block_q: int = 128,
                     block_k: int = 512, interpret: bool = False):
-    """q: (BH, Sq, hd); k, v: (BHkv, Skv, hd), BH = BHkv * G.
-    Returns (BH, Sq, hd) in q's dtype. Sq/Skv are padded to block multiples
-    internally; hd should be 128-aligned for MXU efficiency (any hd works
-    functionally)."""
+    """q: (BH, Sq, hd); k: (BHkv, Skv, hd); v: (BHkv, Skv, hd_v),
+    BH = BHkv * G. Returns (BH, Sq, hd_v) in q's dtype. Sq/Skv are padded
+    to block multiples internally; hd and hd_v should be 128-aligned for
+    MXU efficiency (any width works functionally)."""
     bh, sq, hd = q.shape
     bhkv, skv, _ = k.shape
+    hd_v = v.shape[-1]
     g = bh // bhkv
     scale = scale or (1.0 / math.sqrt(hd))
     bq = min(block_q, max(sq, 8))
@@ -114,14 +116,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
         in_specs=[
             pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((1, bk, hd), lambda b, i, j, g_=g: (b // g_, j, 0)),
-            pl.BlockSpec((1, bk, hd), lambda b, i, j, g_=g: (b // g_, j, 0)),
+            pl.BlockSpec((1, bk, hd_v), lambda b, i, j, g_=g: (b // g_, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, hd), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, sq + pq, hd), q.dtype),
+        out_specs=pl.BlockSpec((1, bq, hd_v), lambda b, i, j: (b, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bh, sq + pq, hd_v), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
-            pltpu.VMEM((bq, hd), jnp.float32),
+            pltpu.VMEM((bq, hd_v), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),

@@ -16,19 +16,21 @@ from repro.kernels import rglru_scan as _rg
 
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     scale: float = 0.0):
-    """q: (B, S, H, hd); k, v: (B, Skv, Hkv, hd) -> (B, S, H, hd)."""
+    """q: (B, S, H, hd); k: (B, Skv, Hkv, hd); v: (B, Skv, Hkv, hd_v)
+    -> (B, S, H, hd_v)."""
     bsz, sq, h, hd = q.shape
     _, skv, hkv, _ = k.shape
+    hd_v = v.shape[-1]
     g = h // hkv
     # fold (B, Hkv, G) so consecutive q heads share a kv head block
     qf = (q.transpose(0, 2, 1, 3)
            .reshape(bsz, hkv, g, sq, hd)
            .reshape(bsz * hkv * g, sq, hd))
     kf = k.transpose(0, 2, 1, 3).reshape(bsz * hkv, skv, hd)
-    vf = v.transpose(0, 2, 1, 3).reshape(bsz * hkv, skv, hd)
+    vf = v.transpose(0, 2, 1, 3).reshape(bsz * hkv, skv, hd_v)
     o = _fa.flash_attention(qf, kf, vf, causal=causal, window=window,
                             scale=scale)
-    return (o.reshape(bsz, hkv * g, sq, hd).transpose(0, 2, 1, 3))
+    return (o.reshape(bsz, hkv * g, sq, hd_v).transpose(0, 2, 1, 3))
 
 
 def rglru_scan(a, b, h0):

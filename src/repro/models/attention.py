@@ -47,9 +47,11 @@ def blocked_attention(q, k, v, *, causal: bool, window: int, q_offset: int,
     """Online-softmax blocked attention (flash-style, pure JAX).
 
     Scans q chunks (outer) and kv chunks (inner) carrying (m, l, acc); memory
-    is O(B·H·chunk_q·hd) instead of O(S²).
+    is O(B·H·chunk_q·hd) instead of O(S²). Values may be narrower than
+    queries and keys (MLA: 128 beside 192).
     """
-    b, sq, hkv, g, hd = q.shape[0], q.shape[1], k.shape[2], q.shape[2] // k.shape[2], q.shape[3]
+    b, sq, hkv, g = q.shape[0], q.shape[1], k.shape[2], q.shape[2] // k.shape[2]
+    hdv = v.shape[3]
     skv_real = k.shape[1]
     cq = min(chunk_q, sq)
     ck = min(chunk_kv, skv_real)
@@ -105,7 +107,7 @@ def blocked_attention(q, k, v, *, causal: bool, window: int, q_offset: int,
 
         m0 = jnp.full((b, hkv, g, cq), NEG_INF, jnp.float32)
         l0 = jnp.zeros((b, hkv, g, cq), jnp.float32)
-        a0 = jnp.zeros((b, hkv, g, cq, hd), jnp.float32)
+        a0 = jnp.zeros((b, hkv, g, cq, hdv), jnp.float32)
         (m, l, acc), _ = jax.lax.scan(
             kv_step, (m0, l0, a0), (kc, vc, jnp.arange(nk)))
         out = acc / jnp.maximum(l, 1e-30)[..., None]          # (B,Hkv,G,cq,hd)
@@ -113,8 +115,8 @@ def blocked_attention(q, k, v, *, causal: bool, window: int, q_offset: int,
 
     _, outs = jax.lax.scan(jax.checkpoint(q_step), None,
                            (qc, jnp.arange(nq)))
-    out = jnp.moveaxis(outs, 0, 1).reshape(b, sq + pq, hkv * g, hd)
-    return out[:, :sq]                                        # (B,Sq,H,hd)
+    out = jnp.moveaxis(outs, 0, 1).reshape(b, sq + pq, hkv * g, hdv)
+    return out[:, :sq]                                        # (B,Sq,H,hdv)
 
 
 def windowed_attention(q, k, v, *, window: int, chunk_q: int, scale: float):

@@ -11,6 +11,10 @@ from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
 
+#: mixer kinds that are attention (and so carry an MLP or MoE after them)
+ATTENTION_KINDS = ("attn", "swa", "local", "mla")
+
+
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -32,14 +36,37 @@ class ModelConfig:
     mrope_sections: Tuple[int, ...] = (16, 24, 24)
 
     # --- MoE options ---
-    n_experts: int = 0
+    n_experts: int = 0             # routed experts the router scores
     topk: int = 0
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
+    moe_d_ff: int = 0              # routed-expert width (0 -> d_ff)
+    n_shared_experts: int = 0      # shared experts: one SwiGLU, n * moe_ff
+    first_k_dense: int = 0         # leading layers with a dense MLP
+    # expert parallelism: this chip holds experts [shard*held, (shard+1)*held)
+    # and computes only their part, dropless (0 -> all, capacity dispatch)
+    experts_held: int = 0
+    expert_shard: int = 0
+    norm_topk_prob: bool = True    # renormalize the top-k gate weights
+    routed_scaling: float = 1.0
+
+    # --- multi-head latent attention (kind "mla"; DeepSeek-V2) ---
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+
+    # --- YaRN rope scaling (0 = plain RoPE) ---
+    yarn_factor: float = 0.0
+    yarn_original_max_pos: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
 
     # --- layer pattern ---
     # Unit of block kinds repeated down the stack; remainder handled
-    # explicitly. Kinds: attn | swa | local | mlstm | slstm | rglru
+    # explicitly. Kinds: attn | swa | local | mla | mlstm | slstm | rglru
     pattern_unit: Tuple[str, ...] = ("attn",)
 
     # --- recurrent widths ---
@@ -71,6 +98,14 @@ class ModelConfig:
     @property
     def hd(self) -> int:
         return self.head_dim or (self.d_model // self.n_heads)
+
+    @property
+    def moe_ff(self) -> int:
+        return self.moe_d_ff or self.d_ff
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
 
     @property
     def is_encoder_only(self) -> bool:
@@ -105,14 +140,20 @@ class ModelConfig:
         return count_params(self)
 
     def n_active_params(self) -> int:
-        """Params touched per token (MoE: only topk experts active)."""
+        """Params touched per token (MoE: only topk routed experts active,
+        of a held share ``topk * held / n_experts`` on average; the leading
+        dense layers and the shared experts always are)."""
         from repro.models.schema import count_params
         total = count_params(self)
         if self.n_experts and self.topk:
-            # expert FFN params per layer: 3*d*ff each (fused gate|up = 2, down = 1)
-            n_moe_layers = sum(1 for k in self.pattern() if k in ("attn", "swa", "local"))
-            inactive = (self.n_experts - self.topk) * 3 * self.d_model * self.d_ff
-            return total - inactive * n_moe_layers
+            # routed-expert params per layer: 3*d*moe_ff each (fused
+            # gate|up = 2, down = 1), of which n_experts_held are stored
+            n_moe_layers = sum(1 for k in self.pattern()[self.first_k_dense:]
+                               if k in ATTENTION_KINDS)
+            held = self.n_experts_held
+            idle = held - self.topk * held / self.n_experts
+            return total - round(idle * 3 * self.d_model * self.moe_ff
+                                 * n_moe_layers)
         return total
 
 

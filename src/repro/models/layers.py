@@ -7,6 +7,8 @@ stay full precision for the optimizer.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -73,10 +75,45 @@ def _rope_freqs(hd: int, theta: float):
     return 1.0 / (theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd))
 
 
-def apply_rope(x, positions, theta: float):
-    """x: (..., S, H, hd), positions: broadcastable to (..., S)."""
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor ``0.1 * mscale * ln(factor) + 1``."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original: int,
+               beta_fast: float, beta_slow: float):
+    """YaRN (NTK-by-parts) inverse frequencies of a ``dim``-wide rotary
+    head: the original frequencies ``theta ** (-2i/dim)`` where a
+    dimension turns more than ``beta_fast`` times over the original
+    context, those divided by ``factor`` where it turns fewer than
+    ``beta_slow`` times, and a linear ramp between."""
+    def corr(rot):
+        return dim * math.log(original / (rot * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), dim - 1)
+    extra = _rope_freqs(dim, theta)
+    ramp = (jnp.arange(dim // 2, dtype=jnp.float32) - low) \
+        / (high - low if high > low else 0.001)
+    keep = 1.0 - jnp.clip(ramp, 0.0, 1.0)        # 1: extrapolate (original)
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def rope_freqs(cfg: ModelConfig, dim: int):
+    """Inverse frequencies of a ``dim``-wide rotary head under ``cfg``."""
+    if cfg.yarn_factor:
+        return yarn_freqs(dim, cfg.rope_theta, cfg.yarn_factor,
+                          cfg.yarn_original_max_pos, cfg.yarn_beta_fast,
+                          cfg.yarn_beta_slow)
+    return _rope_freqs(dim, cfg.rope_theta)
+
+
+def apply_rope(x, positions, theta: float, inv=None):
+    """x: (..., S, H, hd), positions: broadcastable to (..., S). ``inv``
+    overrides the plain frequencies of ``theta`` (YaRN)."""
     hd = x.shape[-1]
-    inv = _rope_freqs(hd, theta)                              # (hd/2,)
+    if inv is None:
+        inv = _rope_freqs(hd, theta)                          # (hd/2,)
     ang = positions[..., None].astype(jnp.float32) * inv      # (..., S, hd/2)
     cos, sin = jnp.cos(ang)[..., None, :], jnp.sin(ang)[..., None, :]
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)

@@ -20,11 +20,12 @@ import jax.numpy as jnp
 
 from repro.models import recurrent as rec
 from repro.models.attention import KVCache, attn_block
-from repro.models.config import ModelConfig
+from repro.models.config import ATTENTION_KINDS, ModelConfig
 from repro.models.layers import (apply_mlp, apply_norm, cdt, cross_entropy,
                                  embed_tokens, linear, unembed)
+from repro.models.mla import MLACache, mla_block
 from repro.models.moe import apply_moe
-from repro.models.schema import layer_groups
+from repro.models.schema import layer_groups, moe_group
 from repro.sharding import shard_hint
 
 
@@ -41,6 +42,10 @@ def _attn_cache_init(cfg: ModelConfig, kind: str, b: int, cap: int):
 
 def _mixer_cache_init(cfg: ModelConfig, kind: str, b: int, cap: int):
     d = cfg.d_model
+    if kind == "mla":                       # the latent cache
+        return MLACache(
+            jnp.zeros((b, cap, cfg.kv_lora_rank), cdt(cfg)),
+            jnp.zeros((b, cap, cfg.qk_rope_head_dim), cdt(cfg)))
     if kind in ("attn", "swa", "local"):
         return _attn_cache_init(cfg, kind, b, cap)
     if kind == "mlstm":
@@ -69,28 +74,31 @@ def init_cache(cfg: ModelConfig, batch: int, cap: int):
 # ---------------------------------------------------------------------------
 
 def _apply_unit(unit, p_unit, x, cfg: ModelConfig, caches, positions,
-                cache_pos, mode: str, prefill_pad: int = 0):
+                cache_pos, mode: str, prefill_pad: int = 0,
+                moe: bool = False):
     """Apply the blocks of one pattern unit. Returns (x, new_caches, aux).
 
     Named scopes (op-name metadata only): ``attn`` around an attention
-    mixer, its KV-cache slice and update included; the recurrent kind's
-    name around a recurrent mixer; ``mlp`` around the MLP or MoE."""
+    mixer, its KV-cache slice and update included (for MLA, ``attn/latent``
+    around the latent-cache update and the absorbed scores and values); the
+    recurrent kind's name around a recurrent mixer; ``mlp`` around the MLP
+    or MoE (held-expert MoE: ``mlp/router``, ``mlp/experts``,
+    ``mlp/shared``)."""
     aux = jnp.zeros((), jnp.float32)
     new_caches: Dict[str, Any] = {}
     for idx, kind in enumerate(unit):
         bp = p_unit[str(idx)]
         ci = caches.get(str(idx)) if caches is not None else None
-        attention = kind in ("attn", "swa", "local")
+        attention = kind in ATTENTION_KINDS
         with jax.named_scope("attn" if attention else kind):
-            if attention:
+            if kind == "mla":
+                out, c_new = mla_block(bp["mixer"], x, cfg,
+                                       positions=positions, cache=ci,
+                                       cache_pos=cache_pos)
+            elif attention:
                 out, c_new = attn_block(bp["mixer"], x, cfg, kind,
                                         positions=positions, cache=ci,
                                         cache_pos=cache_pos)
-                if mode == "train":
-                    c_new = None
-                elif mode == "prefill":
-                    c_new = _prefill_attn_cache(cfg, kind, c_new,
-                                                prefill_pad)
             elif kind == "mlstm":
                 out, c_new = rec.mlstm_block(bp["mixer"], x, cfg, ci)
             elif kind == "slstm":
@@ -99,10 +107,16 @@ def _apply_unit(unit, p_unit, x, cfg: ModelConfig, caches, positions,
                 out, c_new = rec.rglru_block(bp["mixer"], x, cfg, ci)
             else:
                 raise ValueError(kind)
+            if attention and mode == "train":
+                c_new = None
+            elif attention and mode == "prefill":
+                c_new = (_pad_cache(cfg, c_new, prefill_pad) if kind == "mla"
+                         else _prefill_attn_cache(cfg, kind, c_new,
+                                                  prefill_pad))
         x = shard_hint(x + out, "acts")
         if "mlp" in bp:
             with jax.named_scope("mlp"):
-                if cfg.n_experts:
+                if moe:
                     mo, a = apply_moe(bp["mlp"], x, cfg)
                     aux = aux + a
                 else:
@@ -111,6 +125,16 @@ def _apply_unit(unit, p_unit, x, cfg: ModelConfig, caches, positions,
         if c_new is not None:
             new_caches[str(idx)] = c_new
     return x, (new_caches or None), aux
+
+
+def _pad_cache(cfg: ModelConfig, cache, pad_to: int):
+    """A prefill-computed full-sequence cache (positions on axis 1) padded
+    to ``pad_to`` capacity, in the compute dtype."""
+    def pad(a):
+        extra = max(pad_to - a.shape[1], 0)
+        a = jnp.pad(a, ((0, 0), (0, extra)) + ((0, 0),) * (a.ndim - 2))
+        return a.astype(cdt(cfg))
+    return jax.tree.map(pad, cache)
 
 
 def _prefill_attn_cache(cfg: ModelConfig, kind: str, kv: KVCache,
@@ -181,12 +205,13 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
     for gi, (unit, reps) in enumerate(layer_groups(cfg)):
         gp = params["groups"][str(gi)]
         gcache = cache[str(gi)] if cache is not None else None
+        apply_unit = functools.partial(_apply_unit, moe=moe_group(cfg, gi))
 
         if mode == "train":
             def body(carry, p_unit, _unit=unit):
                 xc, auxc = carry
-                xo, _, a = _apply_unit(_unit, p_unit, xc, cfg, None,
-                                       positions, cache_pos, mode)
+                xo, _, a = apply_unit(_unit, p_unit, xc, cfg, None,
+                                      positions, cache_pos, mode)
                 return (xo, auxc + a), None
             k = _group_k(cfg)
             if k > 1 and reps % k == 0 and reps > k:
@@ -200,8 +225,8 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
                 def group_body(carry, p_group, _unit=unit):
                     def inner(c, p_u):
                         xc, auxc = c
-                        xo, _, a = _apply_unit(_unit, p_u, xc, cfg, None,
-                                               positions, cache_pos, mode)
+                        xo, _, a = apply_unit(_unit, p_u, xc, cfg, None,
+                                              positions, cache_pos, mode)
                         return (xo, auxc + a), None
                     # recursive: the inner layers are checkpointed too,
                     # else the group recompute saves k layers of internals
@@ -223,16 +248,16 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
             def body(carry, xs, _unit=unit):
                 xc, auxc = carry
                 p_unit, caches = xs
-                xo, c_new, a = _apply_unit(_unit, p_unit, xc, cfg, caches,
-                                           positions, cache_pos, mode)
+                xo, c_new, a = apply_unit(_unit, p_unit, xc, cfg, caches,
+                                          positions, cache_pos, mode)
                 return (xo, auxc + a), c_new
             if mode == "prefill":
                 # caches are produced, not consumed: xs carries params only
                 def body(carry, p_unit, _unit=unit):
                     xc, auxc = carry
-                    xo, c_new, a = _apply_unit(_unit, p_unit, xc, cfg, None,
-                                               positions, cache_pos, mode,
-                                               prefill_pad)
+                    xo, c_new, a = apply_unit(_unit, p_unit, xc, cfg, None,
+                                              positions, cache_pos, mode,
+                                              prefill_pad)
                     return (xo, auxc + a), c_new
                 (x, aux_total), c_out = jax.lax.scan(body, (x, aux_total), gp)
             else:

@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.models.config import ModelConfig
+from repro.models.config import ATTENTION_KINDS, ModelConfig
 
 
 @dataclass(frozen=True)
@@ -78,14 +78,41 @@ def _mlp_schema(cfg: ModelConfig) -> Dict[str, ParamSpec | dict]:
 
 
 def _moe_schema(cfg: ModelConfig) -> Dict[str, ParamSpec | dict]:
-    d, ff, e = cfg.d_model, cfg.d_ff, cfg.n_experts
-    return {
+    """The router scores all ``n_experts``; the expert weights are those of
+    the ``n_experts_held`` experts this chip holds; shared experts (one
+    SwiGLU of width ``n_shared_experts * moe_ff``) see every token."""
+    d, ff, e = cfg.d_model, cfg.moe_ff, cfg.n_experts
+    held = cfg.n_experts_held
+    s: Dict[str, ParamSpec | dict] = {
         "norm": _norm(d, cfg.norm),
         "router": {"w": ParamSpec((d, e), ("embed", None), "normal", 1.0 / math.sqrt(d))},
-        "wi": ParamSpec((e, d, 2 * ff), ("experts", "embed", "ffn"),
+        "wi": ParamSpec((held, d, 2 * ff), ("experts", "embed", "ffn"),
                         "normal", 1.0 / math.sqrt(d)),
-        "wo": ParamSpec((e, ff, d), ("experts", "ffn", "embed"),
+        "wo": ParamSpec((held, ff, d), ("experts", "ffn", "embed"),
                         "normal", 1.0 / math.sqrt(ff)),
+    }
+    if cfg.n_shared_experts:
+        sff = cfg.n_shared_experts * ff
+        s["shared"] = {"wi": _dense(d, 2 * sff, "embed", "ffn"),
+                       "wo": _dense(sff, d, "ffn", "embed")}
+    return s
+
+
+def _mla_schema(cfg: ModelConfig) -> Dict[str, ParamSpec | dict]:
+    """Multi-head latent attention (DeepSeek-V2, no query compression):
+    per head, ``wq`` gives [nope | rope] query columns; ``wkv_a`` gives the
+    joint latent ``c_kv`` (``kv_lora_rank``, normed by ``kv_norm``) and one
+    rope key shared by all heads; ``wkv_b`` expands the latent to per-head
+    [k_nope | v]."""
+    d, h, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "norm": _norm(d, cfg.norm),
+        "wq": _dense(d, h * (nope + rope), "embed", "qkv"),
+        "wkv_a": _dense(d, r + rope, "embed", None),
+        "kv_norm": {"scale": ParamSpec((r,), (None,), "ones")},
+        "wkv_b": _dense(r, h * (nope + vd), None, "qkv"),
+        "wo": _dense(h * vd, d, "qkv", "embed"),
     }
 
 
@@ -144,14 +171,15 @@ def _slstm_schema(cfg: ModelConfig) -> Dict[str, ParamSpec | dict]:
 
 _KIND_SCHEMA = {
     "attn": _attn_schema, "swa": _attn_schema, "local": _attn_schema,
-    "rglru": _rglru_schema, "mlstm": _mlstm_schema, "slstm": _slstm_schema,
+    "mla": _mla_schema, "rglru": _rglru_schema, "mlstm": _mlstm_schema, "slstm": _slstm_schema,
 }
 
 
-def _block_schema(cfg: ModelConfig, kind: str) -> Dict[str, ParamSpec | dict]:
+def _block_schema(cfg: ModelConfig, kind: str,
+                  moe: bool) -> Dict[str, ParamSpec | dict]:
     s = {"mixer": _KIND_SCHEMA[kind](cfg)}
-    if cfg.d_ff > 0 and kind in ("attn", "swa", "local"):
-        s["mlp"] = _moe_schema(cfg) if cfg.n_experts else _mlp_schema(cfg)
+    if cfg.d_ff > 0 and kind in ATTENTION_KINDS:
+        s["mlp"] = _moe_schema(cfg) if moe else _mlp_schema(cfg)
     return s
 
 
@@ -160,15 +188,27 @@ def _block_schema(cfg: ModelConfig, kind: str) -> Dict[str, ParamSpec | dict]:
 # ---------------------------------------------------------------------------
 
 def layer_groups(cfg: ModelConfig):
-    """[(unit_kinds, repeats), ...] covering all n_layers in order."""
+    """[(unit_kinds, repeats), ...] covering all n_layers in order. The
+    ``first_k_dense`` leading layers (whole units) form a group of their
+    own: the one group whose MLP is dense in an MoE model."""
     unit = cfg.pattern_unit
-    reps, rem = divmod(cfg.n_layers, len(unit))
     groups = []
+    dense = cfg.first_k_dense
+    if dense:
+        if dense % len(unit):
+            raise ValueError("first_k_dense must cover whole pattern units")
+        groups.append((unit, dense // len(unit)))
+    reps, rem = divmod(cfg.n_layers - dense, len(unit))
     if reps:
         groups.append((unit, reps))
     if rem:
         groups.append((unit[:rem], 1))
     return groups
+
+
+def moe_group(cfg: ModelConfig, gi: int) -> bool:
+    """Whether group ``gi`` of ``layer_groups`` has MoE MLPs."""
+    return bool(cfg.n_experts) and not (cfg.first_k_dense and gi == 0)
 
 
 def _stack(tree, n: int):
@@ -187,8 +227,9 @@ def schema(cfg: ModelConfig) -> Dict:
         s["embed"] = {"w": ParamSpec((cfg.vocab_size, d), ("vocab", "embed"),
                                      "normal", 0.02)}
     groups = []
-    for unit, reps in layer_groups(cfg):
-        g = {str(i): _block_schema(cfg, kind) for i, kind in enumerate(unit)}
+    for gi, (unit, reps) in enumerate(layer_groups(cfg)):
+        g = {str(i): _block_schema(cfg, kind, moe_group(cfg, gi))
+             for i, kind in enumerate(unit)}
         groups.append(_stack(g, reps) if cfg.scan_layers else _unroll(g, reps))
     s["groups"] = {str(i): g for i, g in enumerate(groups)}
     s["final_norm"] = _norm(d, cfg.norm)

@@ -103,6 +103,7 @@ def test_full_configs_match_spec():
         "recurrentgemma-2b": (26, 2560, 10, 1, 7680, 256000),
         "hubert-xlarge": (48, 1280, 16, 16, 5120, 504),
         "qwen2-vl-72b": (80, 8192, 64, 8, 29568, 152064),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
     }
     for arch, (l, d, h, kv, ff, v) in spec.items():
         c = get_config(arch)
@@ -111,7 +112,7 @@ def test_full_configs_match_spec():
 
 
 def test_cell_support_matrix():
-    """40 cells; the documented 8 skips and 32 live cells."""
+    """44 cells; the documented 9 skips and 35 live cells."""
     live = skips = 0
     for a in ARCH_IDS:
         for s in SHAPES.values():
@@ -120,7 +121,7 @@ def test_cell_support_matrix():
             skips += not ok
             if not ok:
                 assert reason
-    assert live == 32 and skips == 8
+    assert live == 35 and skips == 9
 
 
 def test_trainer_resume_bit_identical(tmp_path):
@@ -157,7 +158,7 @@ def test_param_counts_reasonable():
     approx = {"mixtral-8x7b": 46.7e9, "granite-8b": 8.1e9,
               "qwen1.5-0.5b": 0.62e9, "smollm-360m": 0.36e9,
               "recurrentgemma-2b": 2.7e9, "qwen2-vl-72b": 72.7e9,
-              "xlstm-350m": 0.35e9}
+              "xlstm-350m": 0.35e9, "deepseek-v2-lite": 15.7e9}
     for arch, expect in approx.items():
         n = count_params(get_config(arch))
         assert 0.6 * expect < n < 1.55 * expect, (arch, n, expect)

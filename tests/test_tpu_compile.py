@@ -99,6 +99,16 @@ def test_flash_attention_compiles_at_smollm_widths(one_chip, seq):
     assert "tpu_custom_call" in text
 
 
+def test_flash_attention_compiles_at_mla_widths(one_chip):
+    """DeepSeek-V2-Lite prefill: 16 heads, 192-wide queries and keys beside
+    128-wide values, batch 2 of 4,096 tokens."""
+    q = _shape((2 * 16, 4096, 192), jnp.bfloat16, one_chip)
+    v = _shape((2 * 16, 4096, 128), jnp.bfloat16, one_chip)
+    compiled = flash_attention.lower(q, q, v).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.out_info.shape == (2 * 16, 4096, 128)
+
+
 @pytest.mark.parametrize("seq", [2048, 8192])
 def test_rglru_scan_compiles_at_recurrentgemma_width(one_chip, seq):
     """recurrentgemma-2b's RG-LRU width (2560); the sequence streams
