@@ -16,6 +16,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.models.config import ModelConfig
 from repro.models.layers import apply_mrope, apply_norm, apply_rope, cdt, linear
@@ -23,11 +24,53 @@ from repro.sharding import shard_hint
 
 
 class KVCache(NamedTuple):
-    k: jax.Array          # (B, Smax, Hkv, hd)
+    k: jax.Array          # (B, Smax, Hkv, hd); (L, B, Smax, Hkv, hd) stacked
     v: jax.Array
 
 
 NEG_INF = -1e30
+
+
+def cache_layer(stack, layer):
+    """Layer ``layer`` of a cache stacked over layers (leading axis), a
+    slice of ``stack`` for the einsums that read it."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+        stack)
+
+
+def cache_write(stack, layer, new, slot=None):
+    """``stack`` with ``new`` written into layer ``layer`` in place.
+
+    Each leaf of ``new`` is a layer's leaf: the rows (B, 1, ...) of one
+    step, put at position ``slot`` of a positions axis (axis 2 of the
+    stack), or with ``slot`` None a whole recurrent state. A stack of rows
+    keeps, on a TPU, the layout it arrives with (``_tpu_cache_layout``)."""
+    def put(a, n):
+        start = [layer] + [0] * (a.ndim - 1)
+        if slot is not None:
+            start[2] = slot
+        a = jax.lax.dynamic_update_slice(a, n[None].astype(a.dtype), start)
+        if slot is None:
+            return a
+        return jax.lax.platform_dependent(a, tpu=_tpu_cache_layout,
+                                          default=lambda x: x)
+    return jax.tree.map(put, stack, new)
+
+
+def _tpu_cache_layout(a):
+    """Pin a cache stack (L, B, S, ...) inside the decode loop to the layout
+    a TPU gives such an array by default, so that the loop neither copies
+    the stack on the way in and out nor copies each layer for its einsums:
+    rows a multiple of 128 lanes wide stay minor (row-major: the einsums
+    read a layer in place, and the write of one step's rows pulls towards
+    batch-minor without it); narrower rows (64-wide heads, MLA's rope key)
+    go position-minor, the positions on the lanes. A row-major cache keeps
+    that layout only with a multiple of 8 positions (``cache_capacity``)."""
+    order = tuple(range(a.ndim))
+    if a.shape[-1] % 128:
+        order = order[:2] + order[3:] + (2,)
+    return with_layout_constraint(a, Layout(order))
 
 
 def _fold_gqa(q, n_kv: int):
@@ -187,11 +230,14 @@ def decode_attention(q, cache: KVCache, pos, *, window: int, scale: float):
 
 def attn_block(p, x, cfg: ModelConfig, kind: str, *,
                positions=None, cache: Optional[KVCache] = None,
-               cache_pos=None, layer_window: int = 0):
+               cache_pos=None, layer=None, layer_window: int = 0):
     """Returns (out, new_cache). kind: attn | swa | local.
 
     Train/prefill: cache is None (prefill callers build the cache from the
-    returned k/v via ``make_cache``); decode: cache given, x is (B,1,d).
+    returned k/v via ``make_cache``); decode: x is (B,1,d), ``cache`` is
+    the group's cache stacked over layers and ``layer`` this layer's index
+    in it. The step's K and V rows are written into the stack in place,
+    then attention reads the layer out of the updated stack.
     """
     b, s, _ = x.shape
     hd = cfg.hd
@@ -220,15 +266,14 @@ def attn_block(p, x, cfg: ModelConfig, kind: str, *,
         k = apply_rope(k, positions, cfg.rope_theta)
 
     if cache is not None:                                     # decode
-        slot = cache_pos if window == 0 else cache_pos % cache.k.shape[1]
-        nk = jax.lax.dynamic_update_slice_in_dim(cache.k, k.astype(cache.k.dtype), slot, axis=1)
-        nv = jax.lax.dynamic_update_slice_in_dim(cache.v, v.astype(cache.v.dtype), slot, axis=1)
-        new_cache = KVCache(nk, nv)
+        slot = cache_pos if window == 0 else cache_pos % cache.k.shape[2]
+        new_cache = cache_write(cache, layer, KVCache(k, v), slot)
+        kv = cache_layer(new_cache, layer)
         if window == 0:
-            out = decode_attention(q, new_cache, cache_pos, window=0, scale=scale)
+            out = decode_attention(q, kv, cache_pos, window=0, scale=scale)
         else:
             # ring-buffer cache of size window: every live entry is in range
-            out = _decode_ring(q, new_cache, cache_pos, window, scale)
+            out = _decode_ring(q, kv, cache_pos, window, scale)
         out = out.reshape(b, s, h * hd)
         return linear(p["wo"], out.astype(cdt(cfg)), cfg), new_cache
 

@@ -25,7 +25,8 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.attention import NEG_INF, blocked_attention
+from repro.models.attention import (NEG_INF, blocked_attention, cache_layer,
+                                    cache_write)
 from repro.models.config import ModelConfig
 from repro.models.layers import (apply_norm, apply_rope, cdt, linear,
                                  rope_freqs, yarn_mscale)
@@ -34,6 +35,7 @@ from repro.models.layers import (apply_norm, apply_rope, cdt, linear,
 class MLACache(NamedTuple):
     c_kv: jax.Array       # (B, Smax, kv_lora_rank)  normed latent
     k_pe: jax.Array       # (B, Smax, qk_rope_head_dim)  rotated
+    # (a decode step holds them stacked over layers: (L, B, Smax, ...))
 
 
 def softmax_scale(cfg: ModelConfig) -> float:
@@ -71,10 +73,13 @@ def _project(p, x, cfg: ModelConfig, positions):
 
 
 def mla_block(p, x, cfg: ModelConfig, *, positions=None,
-              cache: Optional[MLACache] = None, cache_pos=None):
+              cache: Optional[MLACache] = None, cache_pos=None, layer=None):
     """Returns (out, new_cache). Train/prefill: ``cache`` is None and the
     returned cache holds the sequence's latent ``(c_kv, k_pe)``; decode:
-    ``x`` is (B, 1, d) and the latent is written at ``cache_pos``."""
+    ``x`` is (B, 1, d), ``cache`` is the group's latent cache stacked over
+    layers and ``layer`` this layer's index in it: the step's latent row is
+    written into the stack in place at ``cache_pos``, then the absorbed
+    scores and values read the layer out of the updated stack."""
     b, s, _ = x.shape
     h, nope, vd = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
     r = cfg.kv_lora_rank
@@ -86,12 +91,10 @@ def mla_block(p, x, cfg: ModelConfig, *, positions=None,
 
     if cache is not None:
         with jax.named_scope("latent"):
-            new_cache = MLACache(
-                jax.lax.dynamic_update_slice_in_dim(
-                    cache.c_kv, c_kv.astype(cache.c_kv.dtype), cache_pos, 1),
-                jax.lax.dynamic_update_slice_in_dim(
-                    cache.k_pe, k_pe.astype(cache.k_pe.dtype), cache_pos, 1))
-            out = absorbed_decode(q_nope[:, 0], q_pe[:, 0], new_cache,
+            new_cache = cache_write(cache, layer, MLACache(c_kv, k_pe),
+                                    cache_pos)
+            out = absorbed_decode(q_nope[:, 0], q_pe[:, 0],
+                                  cache_layer(new_cache, layer),
                                   wkv_b[..., :nope], wkv_b[..., nope:],
                                   cache_pos, scale)
         out = out.reshape(b, 1, h * vd).astype(cdt(cfg))

@@ -7,8 +7,11 @@ group; xLSTM is ``(mlstm x3, slstm) x 6``. Scanning keeps the HLO (and
 compile time) independent of depth — essential when dry-running 80-layer
 models for 512 devices.
 
-Caches mirror the group structure with a leading ``repeats`` dim and flow
-through the same scans.
+Caches mirror the group structure with a leading ``repeats`` dim. Prefill
+produces them as the scans' stacked outputs; a decode step carries each
+group's stack through its scan and each layer writes only its new entries
+into it in place (one row per sequence, or a recurrent layer's whole
+state), so no step copies a whole layer's cache.
 """
 from __future__ import annotations
 
@@ -19,7 +22,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import recurrent as rec
-from repro.models.attention import KVCache, attn_block
+from repro.models.attention import (KVCache, attn_block, cache_layer,
+                                    cache_write)
 from repro.models.config import ATTENTION_KINDS, ModelConfig
 from repro.models.layers import (apply_mlp, apply_norm, cdt, cross_entropy,
                                  embed_tokens, linear, unembed)
@@ -32,6 +36,13 @@ from repro.sharding import shard_hint
 # ---------------------------------------------------------------------------
 # caches
 # ---------------------------------------------------------------------------
+
+def cache_capacity(n: int) -> int:
+    """Positions a decode cache holds when ``n`` are asked for: ``n``
+    rounded up to a multiple of 8, the TPU's sublane tile, so that a cache
+    of rows a multiple of 128 wide keeps its row-major layout there."""
+    return -(-n // 8) * 8
+
 
 def _attn_cache_init(cfg: ModelConfig, kind: str, b: int, cap: int):
     window = cfg.window if kind in ("swa", "local") else 0
@@ -59,7 +70,9 @@ def _mixer_cache_init(cfg: ModelConfig, kind: str, b: int, cap: int):
 
 
 def init_cache(cfg: ModelConfig, batch: int, cap: int):
-    """Decode cache pytree matching the params group structure."""
+    """Decode cache pytree matching the params group structure, of
+    ``cache_capacity(cap)`` positions."""
+    cap = cache_capacity(cap)
     groups = {}
     for gi, (unit, reps) in enumerate(layer_groups(cfg)):
         g = {str(i): _mixer_cache_init(cfg, kind, batch, cap)
@@ -75,12 +88,16 @@ def init_cache(cfg: ModelConfig, batch: int, cap: int):
 
 def _apply_unit(unit, p_unit, x, cfg: ModelConfig, caches, positions,
                 cache_pos, mode: str, prefill_pad: int = 0,
-                moe: bool = False):
+                moe: bool = False, layer=None):
     """Apply the blocks of one pattern unit. Returns (x, new_caches, aux).
 
+    In decode, ``caches`` are the unit's caches stacked over the group's
+    layers and ``layer`` is this unit's index in them; the returned caches
+    are the stacks with this layer's new entries written in.
+
     Named scopes (op-name metadata only): ``attn`` around an attention
-    mixer, its KV-cache slice and update included (for MLA, ``attn/latent``
-    around the latent-cache update and the absorbed scores and values); the
+    mixer, its cache write and read included (for MLA, ``attn/latent``
+    around the latent-cache write and the absorbed scores and values); the
     recurrent kind's name around a recurrent mixer; ``mlp`` around the MLP
     or MoE (held-expert MoE: ``mlp/router``, ``mlp/experts``,
     ``mlp/shared``)."""
@@ -94,19 +111,18 @@ def _apply_unit(unit, p_unit, x, cfg: ModelConfig, caches, positions,
             if kind == "mla":
                 out, c_new = mla_block(bp["mixer"], x, cfg,
                                        positions=positions, cache=ci,
-                                       cache_pos=cache_pos)
+                                       cache_pos=cache_pos, layer=layer)
             elif attention:
                 out, c_new = attn_block(bp["mixer"], x, cfg, kind,
                                         positions=positions, cache=ci,
-                                        cache_pos=cache_pos)
-            elif kind == "mlstm":
-                out, c_new = rec.mlstm_block(bp["mixer"], x, cfg, ci)
-            elif kind == "slstm":
-                out, c_new = rec.slstm_block(bp["mixer"], x, cfg, ci)
-            elif kind == "rglru":
-                out, c_new = rec.rglru_block(bp["mixer"], x, cfg, ci)
+                                        cache_pos=cache_pos, layer=layer)
             else:
-                raise ValueError(kind)
+                block = {"mlstm": rec.mlstm_block, "slstm": rec.slstm_block,
+                         "rglru": rec.rglru_block}[kind]
+                state = None if ci is None else cache_layer(ci, layer)
+                out, c_new = block(bp["mixer"], x, cfg, state)
+                if ci is not None:
+                    c_new = cache_write(ci, layer, c_new)
             if attention and mode == "train":
                 c_new = None
             elif attention and mode == "prefill":
@@ -186,7 +202,8 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
             prefill_pad: int = 0):
     """Run the stack. Returns (x_final, new_cache, aux_loss).
 
-    mode: train (no caches) | prefill (produce caches) | decode (consume).
+    mode: train (no caches) | prefill (produce caches) | decode (update the
+    given caches in place).
     """
     if embeds is not None:
         x = linear(params["frontend_proj"], embeds.astype(cdt(cfg)), cfg)
@@ -244,26 +261,27 @@ def forward(params, cfg: ModelConfig, *, tokens=None, embeds=None,
             else:
                 (x, aux_total), _ = jax.lax.scan(
                     _remat(body, cfg), (x, aux_total), gp)
-        else:
-            def body(carry, xs, _unit=unit):
+        elif mode == "prefill":
+            # caches are produced, not consumed: xs carries params only
+            def body(carry, p_unit, _unit=unit):
                 xc, auxc = carry
-                p_unit, caches = xs
-                xo, c_new, a = apply_unit(_unit, p_unit, xc, cfg, caches,
-                                          positions, cache_pos, mode)
+                xo, c_new, a = apply_unit(_unit, p_unit, xc, cfg, None,
+                                          positions, cache_pos, mode,
+                                          prefill_pad)
                 return (xo, auxc + a), c_new
-            if mode == "prefill":
-                # caches are produced, not consumed: xs carries params only
-                def body(carry, p_unit, _unit=unit):
-                    xc, auxc = carry
-                    xo, c_new, a = apply_unit(_unit, p_unit, xc, cfg, None,
-                                              positions, cache_pos, mode,
-                                              prefill_pad)
-                    return (xo, auxc + a), c_new
-                (x, aux_total), c_out = jax.lax.scan(body, (x, aux_total), gp)
-            else:
-                (x, aux_total), c_out = jax.lax.scan(
-                    body, (x, aux_total), (gp, gcache))
-            new_cache[str(gi)] = c_out
+            (x, aux_total), new_cache[str(gi)] = jax.lax.scan(
+                body, (x, aux_total), gp)
+        else:
+            # the group's cache rides in the carry, updated in place layer
+            # by layer; xs carries the params and each layer's index
+            def body(carry, xs, _unit=unit):
+                xc, auxc, cc = carry
+                p_unit, i = xs
+                xo, cc, a = apply_unit(_unit, p_unit, xc, cfg, cc, positions,
+                                       cache_pos, mode, layer=i)
+                return (xo, auxc + a, cc), None
+            (x, aux_total, new_cache[str(gi)]), _ = jax.lax.scan(
+                body, (x, aux_total, gcache), (gp, jnp.arange(reps)))
 
     x = apply_norm(params["final_norm"], x, cfg)
     return x, (new_cache or None), aux_total
@@ -322,17 +340,20 @@ def loss_fn(params, cfg: ModelConfig, batch):
 
 def prefill(params, cfg: ModelConfig, *, tokens=None, embeds=None,
             positions=None, pad_to: int = 0):
-    """Returns (last_token_logits, cache)."""
+    """Returns (last_token_logits, cache); with ``pad_to``, the cache holds
+    ``cache_capacity(pad_to)`` positions."""
     x, cache, _ = forward(params, cfg, tokens=tokens, embeds=embeds,
                           positions=positions, mode="prefill",
-                          prefill_pad=pad_to)
+                          prefill_pad=cache_capacity(pad_to))
     logits = lm_logits(params, cfg, x[:, -1:, :])
     return logits[:, 0, :], cache
 
 
 def decode_step(params, cfg: ModelConfig, cache, token, pos):
     """One decode step. token: (B, 1) int32; pos: scalar int32 (write slot).
-    Returns (logits (B, V), new_cache)."""
+    Returns (logits (B, V), new_cache): ``cache`` with the step's entries
+    written in, the same structure (jitted with ``cache`` donated, the new
+    cache takes its buffers)."""
     x, new_cache, _ = forward(params, cfg, tokens=token, cache=cache,
                               cache_pos=pos, mode="decode")
     logits = lm_logits(params, cfg, x)

@@ -37,7 +37,8 @@ class EngineConfig:
 class Engine:
     def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig):
         self.cfg, self.params, self.ecfg = cfg, params, ecfg
-        self.decode_fn = jax.jit(make_decode_step(cfg))
+        # the cache is donated: each step writes its rows into it in place
+        self.decode_fn = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
 
     def _sample(self, logits, rng):
         if self.ecfg.temperature <= 0.0:

@@ -6,6 +6,7 @@ parameter count, and the flash kernel's narrower value heads."""
 from __future__ import annotations
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -139,7 +140,8 @@ def test_engine_serves_the_latent_cache_through_its_normal_path():
     assert (np.asarray(jnp.argmax(lg, -1)) == np.asarray(seq[:, 12:])).all()
     cache = M.init_cache(cfg, 2, 19)
     assert isinstance(cache["1"]["0"], mla.MLACache)
-    assert cache["1"]["0"].c_kv.shape == (2, 2, 19, cfg.kv_lora_rank)
+    assert M.cache_capacity(19) == 24        # a multiple of 8 positions
+    assert cache["1"]["0"].c_kv.shape == (2, 2, 24, cfg.kv_lora_rank)
 
 
 def test_decode_step_keeps_no_f32_copy_of_the_latent_cache():
@@ -150,9 +152,9 @@ def test_decode_step_keeps_no_f32_copy_of_the_latent_cache():
     text = jax.jit(lambda p, c, t, i: M.decode_step(p, cfg, c, t, i)).lower(
         params, cache, jax.ShapeDtypeStruct((4, 1), jnp.int32),
         jax.ShapeDtypeStruct((), jnp.int32)).as_text()
-    assert "tensor<4x96x512xbf16>" in text
-    assert "tensor<4x96x512xf32>" not in text
-    assert "tensor<1x4x96x512xf32>" not in text
+    assert "tensor<4x96x512xbf16>" in text   # a layer read from the stack
+    # no f32 tensor of a layer's latent cache, or of the stack of them
+    assert not re.search(r"tensor<[\dx]*4x96x512xf32>", text)
 
 
 # -- the expert layer ----------------------------------------------------------

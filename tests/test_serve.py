@@ -482,3 +482,27 @@ def test_engine_matches_stepwise_generation(stepwise, slots, temperature,
                  if o[-1] == engine.ecfg.eos_id}
         assert len(stops) >= 2 and min(stops) < max_new
     assert engine.generate(ID_PROMPTS, max_new) == want
+
+
+def test_decode_step_donates_the_cache_and_keeps_its_name(stepwise):
+    """One ``engine.decode_fn`` call consumes the cache it is given: every
+    leaf of it is deleted afterwards (the step writes its rows into those
+    buffers, which the returned cache takes over), and the lowered step
+    keeps the name ``jit_decode_step`` and aliases each cache input to an
+    output."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import model as M
+    engine, _ = stepwise(3, 0.0, 1)
+    _, cache = M.prefill(engine.params, engine.cfg,
+                         tokens=jnp.ones((2, 5), jnp.int32), pad_to=9)
+    args = (engine.params, cache, jnp.zeros((2, 1), jnp.int32),
+            jnp.asarray(5, jnp.int32))
+    text = engine.decode_fn.lower(*args).as_text()
+    assert text.startswith("module @jit_decode_step")
+    leaves = jax.tree.leaves(cache)
+    assert text.count("tf.aliasing_output") == len(leaves)
+    _, new = engine.decode_fn(*args)
+    assert all(a.is_deleted() for a in leaves)
+    assert jax.tree.structure(new) == jax.tree.structure(cache)

@@ -13,7 +13,7 @@ from repro.data.pipeline import DataConfig
 from repro.models import model as M
 from repro.models.config import SHAPES, cell_supported
 from repro.models.schema import count_params, init_params
-from repro.models.steps import make_train_step
+from repro.models.steps import make_decode_step, make_train_step
 from repro.optim import adamw
 from repro.train.trainer import Trainer, TrainConfig
 
@@ -71,9 +71,14 @@ DECODE_ARCHS = [a for a in ARCH_IDS
                 and get_smoke(a).frontend is None]
 
 
+@pytest.mark.parametrize("steps", [1, 6])
 @pytest.mark.parametrize("arch", DECODE_ARCHS)
-def test_prefill_decode_consistency(arch):
-    """Gold test: decode(prefill(S-1), token) == full forward at position S."""
+def test_prefill_decode_consistency(arch, steps):
+    """Gold test: after prefill(S - steps), each of ``steps`` decode steps
+    (the engine's jitted step, the cache donated and written in place)
+    gives the full forward's logits at its position. Covers the ring
+    buffer of the windowed kinds wrapping (S - steps is past the smoke
+    window of 16), the recurrent states and MLA's latent cache."""
     cfg = get_smoke(arch)
     if cfg.n_experts:
         cfg = cfg.replace(capacity_factor=float(cfg.n_experts))  # no drops
@@ -81,13 +86,18 @@ def test_prefill_decode_consistency(arch):
     b, s = 2, 24
     tokens = jax.random.randint(jax.random.PRNGKey(2), (b, s), 0,
                                 cfg.vocab_size)
-    ref_logits, _ = M.prefill(params, cfg, tokens=tokens)
-    _, cache = M.prefill(params, cfg, tokens=tokens[:, :s - 1], pad_to=s + 4)
-    dec_logits, _ = M.decode_step(params, cfg, cache, tokens[:, s - 1:s],
-                                  jnp.array(s - 1, jnp.int32))
-    err = float(jnp.max(jnp.abs(ref_logits - dec_logits)))
-    scale = float(jnp.max(jnp.abs(ref_logits)))
-    assert err / max(scale, 1e-9) < 0.05, f"{arch}: decode diverges ({err})"
+    x, _, _ = M.forward(params, cfg, tokens=tokens)
+    ref = M.lm_logits(params, cfg, x)
+    _, cache = M.prefill(params, cfg, tokens=tokens[:, :s - steps],
+                         pad_to=s + 4)
+    step = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
+    for t in range(s - steps, s):
+        dec_logits, cache = step(params, cache, tokens[:, t:t + 1],
+                                 jnp.array(t, jnp.int32))
+        err = float(jnp.max(jnp.abs(ref[:, t] - dec_logits)))
+        scale = float(jnp.max(jnp.abs(ref[:, t])))
+        assert err / max(scale, 1e-9) < 0.05, \
+            f"{arch}: decode diverges at position {t} ({err})"
 
 
 def test_full_configs_match_spec():

@@ -1,6 +1,7 @@
 """Compiles of the main path for a described TPU v5e (2x2 topology): the
 simulator's cohort stepper at paper size, its sharded form on a 4-chip
-mesh, and the Pallas kernels at real model widths. Nothing runs — the
+mesh, the Pallas kernels at real model widths, and the served models'
+decode steps at their cells' widths and shapes. Nothing runs — the
 TPU compiler refuses here what it would refuse on the chip (a lowering it
 lacks, a block that overflows fast memory), at no chip time.
 
@@ -10,6 +11,7 @@ compiler library, and every worker collects the same tests. The
 persistent compilation cache is off around these compiles (an entry
 written for a described chip cannot be read back without one)."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -17,11 +19,15 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+from repro.configs import get_config
 from repro.ggpu import programs
 from repro.ggpu.engine import GGPUConfig
 from repro.ggpu.engine import stepper
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
+from repro.models import model as M
+from repro.models.schema import abstract_params
+from repro.serve import Engine, EngineConfig
 
 COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
                "collective-permute")
@@ -117,3 +123,61 @@ def test_rglru_scan_compiles_at_recurrentgemma_width(one_chip, seq):
     h0 = _shape((1, 2560), jnp.float32, one_chip)
     text = rglru_scan.lower(x, x, h0).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+# The served models' configs at their cells' widths and precision, a few
+# layers deep (the layer scan's body is the same at any depth); decode
+# batch 16, prompt + new tokens + 1 positions, as in the benchmark's cells.
+DECODE_CELLS = {
+    "smollm-360m": (dict(n_layers=3, use_pallas=True), 1024 + 256 + 1),
+    "deepseek-v2-lite": (dict(n_layers=4, experts_held=8, use_pallas=True,
+                              param_dtype="bfloat16"), 4096 + 256 + 1),
+}
+
+
+def _scheduled(text):
+    """(op, element count, line) of each instruction of the entry and the
+    loop bodies: the computations that run, not those fused into others."""
+    bodies = set(re.findall(r"body=%?([\w.\-]+)", text))
+    out, keep = [], False
+    for line in text.splitlines():
+        head = re.match(r"^(ENTRY )?%?([\w.\-]+) ", line)
+        if head and not line.startswith(" "):
+            keep = bool(head.group(1)) or head.group(2) in bodies
+        m = re.match(r"\s+(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S*\s+"
+                     r"([\w\-]+)\(", line)
+        if keep and m:
+            out.append((m.group(2),
+                        int(np.prod([int(d) for d in m.group(1).split(",")
+                                     if d])), line))
+    return out
+
+
+@pytest.mark.parametrize("arch", sorted(DECODE_CELLS))
+def test_decode_step_updates_the_cache_in_place(one_chip, arch):
+    """The engine's decode step, compiled for the chip: the donated cache
+    aliases the output, and no copy, slice or fusion the size of a layer's
+    cache or of a whole stack runs, in the loop or around it; the only ops
+    of that size are the in-place writes of each step's rows."""
+    over, cap = DECODE_CELLS[arch]
+    cfg = get_config(arch).replace(**over)
+    def put(tree):
+        return jax.tree.map(lambda a: _shape(a.shape, a.dtype, one_chip),
+                            tree)
+    cache = put(jax.eval_shape(lambda: M.init_cache(cfg, 16, cap)))
+    engine = Engine(cfg, None, EngineConfig(slots=16))
+    compiled = engine.decode_fn.lower(
+        put(abstract_params(cfg)), cache,
+        _shape((16, 1), jnp.int32, one_chip),
+        _shape((), jnp.int32, one_chip)).compile()
+    leaves = jax.tree.leaves(cache)
+    assert compiled.memory_analysis().alias_size_in_bytes >= sum(
+        a.size * a.dtype.itemsize for a in leaves)
+    sizes = {n for a in leaves for n in (a.size, a.size // a.shape[0])}
+    # copy-start/copy-done into memory space S(1) prefetch a small buffer
+    # into the chip's fast memory; a relayout or a private copy is a copy
+    big = [line.strip()[:160] for op, n, line in _scheduled(compiled.as_text())
+           if n in sizes and op not in ("parameter", "get-tuple-element",
+                                        "bitcast", "dynamic-update-slice")
+           and not (op in ("copy-start", "copy-done") and "S(1)" in line)]
+    assert not big, big
