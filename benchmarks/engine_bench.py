@@ -10,7 +10,7 @@ Measures the execution-engine refactor itself, not the simulated machine:
   * batched launches — N same-kernel launches sequentially vs one
     ``LaunchQueue`` flush (cohort-folded into a single stepper call).
   * async launches   — N single launches serially (each ``run_kernel``
-    blocks on its own download) vs N ``run_kernel_async`` dispatches
+    blocks on its own download) vs N one-launch ``launch`` dispatches
     resolved after the last one is in flight; the cold trace (first
     call, pays the jit compile) is reported separately from both
     steady-state rates.
@@ -107,7 +107,7 @@ def bench_async_launch(emit, n_launches: int = 16, n: int = 512) -> float:
     download of launch k+1 overlap launch k's device compute. Results are
     asserted bit-exact; returns the steady-state speedup."""
     from repro.ggpu import programs
-    from repro.ggpu.engine import GGPUConfig, run_kernel, run_kernel_async
+    from repro.ggpu.engine import GGPUConfig, launch, run_kernel
 
     cfg = GGPUConfig(n_cus=2)
     b = programs._vec_mul(32, n)
@@ -128,7 +128,7 @@ def bench_async_launch(emit, n_launches: int = 16, n: int = 512) -> float:
         return [run_kernel(b.gpu_prog, m, b.gpu_items, cfg) for m in mems]
 
     def asy():
-        handles = [run_kernel_async(b.gpu_prog, m, b.gpu_items, cfg)
+        handles = [launch([b.gpu_prog], [m], [b.gpu_items], cfg)
                    for m in mems]
         return [h.result() for h in handles]
 

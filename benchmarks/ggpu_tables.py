@@ -19,7 +19,7 @@ import numpy as np
 
 from repro.core.planner import enumerate_versions, plan
 from repro.core.ppa import PAPER_TABLE1
-from repro.ggpu.machine import GGPUConfig, ScalarConfig, run_kernel
+from repro.ggpu.engine import GGPUConfig, ScalarConfig, run_kernel
 from repro.ggpu.programs import PAPER_CYCLES, PAPER_INPUT, all_benches
 
 RISCV_AREA_MM2 = 4.19 / 6.5     # paper: 1-CU G-GPU is 6.5x the RISC-V area

@@ -46,7 +46,7 @@ artifact (schema ``ggpu-serve/4``, path overridable via
     submits every stage up front with dependency edges and drains once —
     the dependency-aware scheduler folds each stage across instances
     into one cohort dispatch and feeds producers into consumers entirely
-    on the device (``BlockPatch``), zero host round-trips between
+    on the device (one all-launch ``Patch``), zero host round-trips between
     stages. **host_staged** is the gated baseline: the pre-graph DAG
     idiom, each chain executed stage-by-stage with a full
     ``LaunchHandle`` download and host re-staging per edge (without

@@ -12,8 +12,8 @@ like Gemmini and AutoDNNchip practice):
     (``pipeline_depth``) the analytic map cannot see.
   * ``evaluate`` — ``Evaluator``: end-to-end metrics (wall-clock =
     cycles/fmax, energy, perf/area) per bench, with config-grouped batched
-    simulation (``run_kernel_cohort``/``run_kernel_batch`` via
-    ``LaunchQueue``) and a persistent cycle cache.
+    simulation (cohort or batch ``launch`` calls via a ``Scheduler``
+    drain) and a persistent cycle cache.
   * ``search``   — Pareto-frontier search over {n_cus, frequency target,
     memsys, fuse, pipeline depth}; reports the analytic-only picks the
     cycle-accurate evaluation excludes.

@@ -5,8 +5,8 @@ design points are grouped by their *engine-visible* configuration (the
 frozen ``GGPUConfig`` — frequency targets that plan to the same pipeline
 depth share one simulation), every uncached (config, bench) pair is
 submitted to one ``serve.Scheduler`` drain per config, and the scheduler's
-chunk planner folds same-shape launches through ``run_kernel_cohort`` /
-``run_kernel_batch`` so a whole bench suite costs one or two compiled
+chunk planner folds same-shape launches into cohort (or vmapped batch)
+``launch`` calls so a whole bench suite costs one or two compiled
 stepper dispatches instead of N. Cycle results are memoized on the
 process-wide shared executor (``serve.executors.get_executor``) keyed by
 the bench content, so sweeps, repeat evaluators, and serving fleets that

@@ -7,12 +7,13 @@ implements the executor protocol (``submit`` / ``chunk_ready`` /
 ``collect``; everything else delegates). Faults enter at exactly three
 points:
 
-  * **submit** — launches drawn for a pre-dispatch SEU get an
-    ``XorBlockPatch`` merged into the chunk's staged-memory patches: the
-    bit flip rides the engine's existing fused patch path, so injection
-    costs one XLA dispatch and is *in* the staged buffer the kernel
-    reads — not a host-side fiction. Chunks drawn as stragglers (or
-    dispatched on a wedged device) are recorded in ``_holds``.
+  * **submit** — launches drawn for a pre-dispatch SEU get their flips
+    appended to the chunk's staged-memory patches as one ``op="xor"``
+    ``Patch`` over the hit launches: the bit flip rides the engine's
+    patch path, so injection costs one XLA dispatch and is *in* the
+    staged buffer the kernel reads — not a host-side fiction. Chunks
+    drawn as stragglers (or dispatched on a wedged device) are recorded
+    in ``_holds``.
   * **chunk_ready** — held chunks report not-ready until their hold
     expires (never, for a stuck device). The scheduler's readiness-
     ordered collection and the fleet's hedging both key off this.
@@ -30,12 +31,12 @@ tests compare across runs: same seed, same plan, byte-identical log.
 from __future__ import annotations
 
 import time
-from typing import List, Optional, Sequence
+from typing import List, Sequence
 
 import numpy as np
 
 from repro.faults.plan import FaultPlan
-from repro.ggpu.engine import BlockPatch, XorBlockPatch
+from repro.ggpu.engine import Patch
 from repro.serve.executors import DeviceTimeout, Executor, PendingChunk
 from repro.serve.request import Request, Result
 
@@ -60,13 +61,14 @@ class FaultInjector:
 
     # -- submit: pre-dispatch SEUs + hold decisions --------------------------
 
-    def submit(self, kind: str, reqs: Sequence[Request],
-               patches=None) -> PendingChunk:
+    def submit(self, reqs: Sequence[Request],
+               patches: Sequence[Patch] = ()) -> PendingChunk:
+        reqs = list(reqs)
         ordinal = self._dispatches
         self._dispatches += 1
         if self.plan.seu_rate:
-            patches = self._merge_seu(kind, list(reqs), patches)
-        pending = self.inner.submit(kind, reqs, patches)
+            patches = list(patches) + self._seu_patches(reqs)
+        pending = self.inner.submit(reqs, patches)
         first = reqs[0]
         if self.plan.stuck(self.name, ordinal):
             self._holds[id(pending)] = None
@@ -80,54 +82,27 @@ class FaultInjector:
                                   first.attempts, ordinal))
         return pending
 
-    def _merge_seu(self, kind: str, reqs: List[Request], patches):
-        """Fold this chunk's drawn bit flips into its staged-memory
-        patches. A patch-free cohort gets one fused ``XorBlockPatch``
-        (rows of zeros are no-ops for the unhit launches — one device op
-        covers the chunk); everything else degrades to per-launch
-        ``(lo, hi, mask, "xor")`` entries merged with whatever dependency
-        patches the chunk already carries."""
-        hits = {}
+    def _seu_patches(self, reqs: List[Request]) -> List[Patch]:
+        """This chunk's drawn bit flips as XOR patches, one over the hit
+        launches of each memory size (a cohort has one size). Each mask
+        spans its launches' whole image, so the patch envelope is the
+        same whichever words were hit."""
+        hits: dict = {}                 # memory size -> [(launch, word, bit)]
         for i, r in enumerate(reqs):
             if self.plan.seu_hit(r.ticket, r.attempts):
-                word, bit = self.plan.seu_flip(r.ticket, r.attempts,
-                                               int(r.mem0.shape[0]))
-                hits[i] = (word, bit)
+                msize = int(r.mem0.shape[0])
+                word, bit = self.plan.seu_flip(r.ticket, r.attempts, msize)
+                hits.setdefault(msize, []).append((i, word, bit))
                 self.injected.append(("seu", self.name, r.ticket,
                                       r.attempts, word, bit))
-        if not hits:
-            return patches
-        if patches is None and kind == "cohort" and len(reqs) > 1:
-            # full-width zero block with only the drawn bits set: the
-            # (0, msize) envelope is stable regardless of which words
-            # were hit, so repeated injection reuses one compiled patch
-            # path instead of re-tracing per drawn (lo, hi) span
-            msize = int(reqs[0].mem0.shape[0])
-            block = np.zeros((len(reqs), msize), np.int32)
-            for i, (word, bit) in hits.items():
-                block[i, word] = np.int32(1) << bit
-            return XorBlockPatch(0, msize, block)
-        per = self._per_launch(kind, reqs, patches)
-        for i, (word, bit) in hits.items():
-            # same stable-envelope trick as the fused path: a full-width
-            # mask keeps the patch span at (0, msize) for every draw
-            mask = np.zeros(int(reqs[i].mem0.shape[0]), np.int32)
-            mask[word] = np.int32(1) << bit
-            entry = (0, mask.shape[0], mask, "xor")
-            per[i] = (list(per[i]) + [entry]) if per[i] else [entry]
-        return per
-
-    @staticmethod
-    def _per_launch(kind: str, reqs: List[Request], patches) -> list:
-        """Normalize any chunk-level patch form down to one mutable
-        per-launch list (the form XOR entries can always merge into)."""
-        if patches is None:
-            return [None] * len(reqs)
-        if isinstance(patches, (BlockPatch, XorBlockPatch)):
-            op = ("xor",) if isinstance(patches, XorBlockPatch) else ()
-            return [[(patches.lo, patches.hi, patches.block[i]) + op]
-                    for i in range(len(reqs))]
-        return [list(p) if p else None for p in patches]
+        patches = []
+        for msize, flips in hits.items():
+            masks = np.zeros((len(flips), msize), np.int32)
+            for k, (_, word, bit) in enumerate(flips):
+                masks[k, word] = np.int32(1) << bit
+            patches.append(Patch(0, msize, masks,
+                                 rows=[i for i, _, _ in flips], op="xor"))
+        return patches
 
     # -- readiness + collection: holds, timeouts, post-compute SDC -----------
 

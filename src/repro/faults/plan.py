@@ -19,8 +19,8 @@ by ``(seed, kind, ticket, attempt)``. That keying is the whole design:
 The fault taxonomy (DESIGN.md §Fault injection & self-healing fleet):
 
   * ``seu_rate`` — single-event upset *before* compute: one bit of the
-    launch's staged memory image is flipped pre-dispatch (via the
-    engine's fused ``XorBlockPatch``, one XLA dispatch, off the hot path
+    launch's staged memory image is flipped pre-dispatch (via an
+    ``op="xor"`` engine ``Patch``, one XLA dispatch, off the hot path
     entirely when the rate is 0). The kernel then computes over the
     corrupted input.
   * ``seu_post_rate`` — silent data corruption *after* compute: one bit

@@ -14,33 +14,22 @@ Stage modules (boundaries documented in DESIGN.md):
   * ``scheduler`` — resident-wavefront selection and the lockstep-round
     cycle model
   * ``stepper``   — composition root: the jitted ``while_loop`` machine,
-    fused dispatch, and the single/batched launch entry points
-
-``repro.ggpu.machine`` remains as a thin compatibility facade over this
-package.
+    fused dispatch, and the ``launch`` entry point (cohort or vmapped
+    batch, picked from its input; ``run_kernel`` for one launch)
 """
 from repro.ggpu.engine.alu import branch_taken, exec_alu, select_alu
 from repro.ggpu.engine.config import GGPUConfig, ScalarConfig
 from repro.ggpu.engine.memsys import (MEMSYS_REGISTRY, BankedPerCUCache,
                                       CacheResult, MemorySystem, SharedCache,
                                       get_memsys)
-from repro.ggpu.engine.stepper import (BlockPatch, KernelLaunchError,
-                                       LaunchHandle,
-                                       MachineState, XorBlockPatch,
-                                       cohort_rows,
-                                       launch_shards,
-                                       run_kernel, run_kernel_async,
-                                       run_kernel_batch,
-                                       run_kernel_batch_async,
-                                       run_kernel_cohort,
-                                       run_kernel_cohort_async)
+from repro.ggpu.engine.stepper import (KernelLaunchError, LaunchHandle,
+                                       MachineState, Patch, cohort_rows,
+                                       launch, launch_shards, run_kernel)
 
 __all__ = [
     "GGPUConfig", "ScalarConfig", "MachineState", "KernelLaunchError",
-    "LaunchHandle", "BlockPatch", "XorBlockPatch", "cohort_rows",
-    "launch_shards",
-    "run_kernel", "run_kernel_batch", "run_kernel_cohort",
-    "run_kernel_async", "run_kernel_batch_async", "run_kernel_cohort_async",
+    "LaunchHandle", "Patch", "cohort_rows", "launch", "launch_shards",
+    "run_kernel",
     "exec_alu", "select_alu", "branch_taken",
     "MemorySystem", "SharedCache", "BankedPerCUCache", "CacheResult",
     "MEMSYS_REGISTRY", "get_memsys",

@@ -12,62 +12,63 @@ and stats are bit-identical to one-round-per-iteration dispatch
 The core simulates a **cohort** of ``B`` independent machines by folding
 the batch into the wavefront axis (element e owns wavefronts
 [e*W, (e+1)*W) and the memory words [e*M, (e+1)*M)); cycles/stats/steps
-are tracked per element. ``B == 1`` is the single-launch case.
+are tracked per element. One launch is a cohort of one.
 
-Entry points:
+**One entry point.** ``launch(progs, mems, n_items, cfg)`` dispatches a
+sequence of launches and returns a ``LaunchHandle`` future while the
+device still runs. It picks the path from its input:
 
-  * ``run_kernel``        — single launch; exact signature and bit-exact
-    results of the original monolithic ``machine.run_kernel``.
-    ``legacy=True`` selects the seed-faithful reference stepper
-    (one round per iteration, one-hot scatter cache accounting, dense
-    writeback, unpruned datapath) for differential testing/benchmarks.
-  * ``run_kernel_cohort`` — N launches of the *same kernel* (program,
-    n_items, memory shape) over different memory images, folded into one
-    stepper call: per-round fixed costs are amortized across the cohort
-    and the straight-line fast path stays a real branch. This is the fast
-    multi-launch path ``serve.engine.LaunchQueue`` uses.
-  * ``run_kernel_batch``  — N heterogeneous launches, padded to a common
-    (program, mem) envelope and ``jax.vmap``-ed over the stepper. Fully
-    general (different programs), but vmap turns the fast-path branch into
-    a select, so prefer cohorts where shapes allow.
+  * every launch shares program bytes, item count and memory size (the
+    serving layer's ``Request.kernel_key``): a **cohort**, one
+    ``_run_cohort`` call over the concatenated images — per-round fixed
+    costs are amortized and the straight-line fast path stays a real
+    branch;
+  * otherwise a **batch**: programs padded with HALT words and images
+    zero-padded to a common envelope, ``jax.vmap``-ed over the stepper
+    (``_run_batch``). Fully general, but vmap turns the fast-path branch
+    into a select. Each launch's address clip still binds at its own
+    memory size, so the padding is invisible.
 
-Per-launch cycles/stats are exact in all three: padding a program with
-HALT words and a memory image with zeros is state-invisible to the
-machine, and cohort elements are fully isolated.
+``run_kernel`` is the blocking single-launch wrapper; ``legacy=True``
+there selects the seed-faithful reference stepper (one round per
+iteration, one-hot scatter cache accounting, dense writeback, unpruned
+datapath) for differential testing. Per-launch cycles/stats are exact on
+every path: HALT and zero padding are state-invisible to the machine, and
+cohort elements are fully isolated.
 
-**Sharded execution.** The cohort and batch async entry points accept a
-``mesh=`` (a ``jax.sharding.Mesh``, e.g. ``repro.launch.mesh.
-make_launch_mesh()``): the leading launch axis is then sharded across
-the mesh's data-parallel axes with ``shard_map``, so a fleet of N
-simulated G-GPU instances maps onto M physical devices. Each device
-runs its *own* ``while_loop`` over its slice of the launches — there is
-no cross-device collective anywhere in the machine, so a device retires
-its shard as soon as its own launches halt. Launch counts that do not
-divide the shard count are padded (cohorts with a copy of the first
-image, batches with a 1-item HALT filler); padding is sliced away at
-resolution and never observable. Cohort sizes are additionally bucketed
-to powers of two per shard (``cohort_rows``, sharded or not), so
-open-loop serving traffic with arbitrary pending counts compiles
-O(log B) steppers rather than one per distinct cohort size — the
-compiled-envelope discipline that keeps tail latency flat under Poisson
-arrivals. Per-launch results, cycles, and stats
-are bit-exact vs the single-device path by construction: cohort
-elements are fully isolated, so a B-element cohort split into M local
-(B/M)-element cohorts computes identical bits. A mesh whose
-data-parallel extent is 1 (or ``mesh=None``) falls back to the
-single-device path. Partition specs come from the
-``repro.sharding.rules`` rule engine (the ``"launch"`` activation
-kind). CPU CI simulates 8 devices with
+**Sharding.** A ``mesh`` (e.g. ``repro.launch.mesh.make_launch_mesh()``)
+whose data-parallel extent is above one splits the launch axis of a
+multi-launch call across its devices with ``shard_map``: each device runs
+its *own* ``while_loop`` over its slice — no cross-device collective
+anywhere — so a device retires its shard as soon as its own launches
+halt. Cohorts pad with copies of the first image, batches with 1-item
+HALT fillers; padding is sliced away at resolution and never observable.
+Cohort sizes are bucketed to powers of two per shard (``cohort_rows``,
+sharded or not), so open-loop traffic with arbitrary pending counts
+compiles O(log B) steppers rather than one per cohort size. Partition
+specs come from the ``repro.sharding.rules`` engine (the ``"launch"``
+activation kind). CPU CI simulates 8 devices with
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
 
-**Async launch pipeline.** Every entry point has an ``_async`` twin
-(``run_kernel_async`` / ``run_kernel_cohort_async`` /
-``run_kernel_batch_async``) that returns a ``LaunchHandle`` future
-immediately after dispatch instead of blocking on the device. The sync
-entry points are thin blocking wrappers over the same jitted callables
-(``handle.results()`` right after dispatch), so both paths share one
-compile cache and are bit-exact by construction. Three properties make
-the async path cheap (DESIGN.md §Async launch pipeline):
+**One memory view.** Every staged and final memory is ``S`` staging rows
+of ``b`` launch images of ``msize`` words plus a write sink —
+``(S, b*msize + 1)``: a flat cohort is ``S = 1, b = rows`` (its one row
+kept 1-D), a sharded cohort ``S = shards``, a batch ``S = launches,
+b = 1`` (``msize`` the padded envelope, each launch keeping its own
+size). Launch ``i`` lives at ``divmod(i, b)``; the patch applier, the
+slicer and every handle method work through this view.
+
+**One patch form.** ``Patch(lo, hi, block, rows=None, op="set"|"xor")``
+overwrites (or XORs into) words ``[lo, hi)`` of the launches ``rows``
+(``None``: all of them) in the staged buffer, on the device, before the
+stepper consumes it — ``block`` row ``k`` feeds launch ``rows[k]``. A call
+takes a list, applied in order by one jitted applier on the row view.
+With ``LaunchHandle.device_mem``/``device_mem_block`` as producers, this
+chains kernels with no host transfer (DESIGN.md §Kernel graphs); the
+fault injector's bit flips ride the same path as ``op="xor"``.
+
+**Async.** Three properties make the pipeline cheap (DESIGN.md §Async
+launch pipeline):
 
   * **donation** — the staged memory image (host copy + appended write
     sink) is donated to XLA (``donate_argnums``), so the final memory
@@ -84,7 +85,7 @@ the async path cheap (DESIGN.md §Async launch pipeline):
 from __future__ import annotations
 
 import functools
-from typing import List, NamedTuple, Optional, Sequence, Tuple
+from typing import Any, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -285,25 +286,17 @@ def _build_core(cfg: GGPUConfig, B: int, W: int, prog_len: int, msize: int,
 # The memory argument of each jitted wrapper arrives with the write sink
 # already appended and is DONATED: the machine's final memory aliases the
 # staged input buffer (same shape/dtype), so a launch allocates one memory
-# envelope, not two. Staging (in the *_async entry points) always copies
-# host-side, so a caller's array is never invalidated.
+# envelope, not two. Staging (in ``launch``) always copies host-side, so a
+# caller's array is never invalidated.
 
 @functools.partial(jax.jit,
-                   static_argnames=("cfg", "W", "prog_len", "ops", "legacy"),
+                   static_argnames=("cfg", "B", "W", "prog_len", "ops",
+                                    "legacy"),
                    donate_argnums=(1,))
-def _run_single(prog, mem_sink, n_items, cfg, W, prog_len, ops,
+def _run_cohort(prog, mems_sink, n_items, cfg, B, W, prog_len, ops,
                 legacy=False):
-    msize = mem_sink.shape[0] - 1
-    return _build_core(cfg, 1, W, prog_len, msize, ops, legacy)(
-        prog, mem_sink, n_items, jnp.asarray(msize, jnp.int32))
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("cfg", "B", "W", "prog_len", "ops"),
-                   donate_argnums=(1,))
-def _run_cohort(prog, mems_sink, n_items, cfg, B, W, prog_len, ops):
     msize = (mems_sink.shape[0] - 1) // B
-    return _build_core(cfg, B, W, prog_len, msize, ops)(
+    return _build_core(cfg, B, W, prog_len, msize, ops, legacy)(
         prog, mems_sink, n_items, jnp.asarray(msize, jnp.int32))
 
 
@@ -350,8 +343,8 @@ def _launch_rules(mesh):
 def _launch_spec(mesh, ndim: int):
     """PartitionSpec sharding a leading launch axis of an ``ndim``-array
     over ``mesh``'s data-parallel axes (via the rule engine's ``launch``
-    activation kind — the shard count always divides here because entry
-    points pad first)."""
+    activation kind — the shard count always divides here because
+    ``launch`` pads first)."""
     rules = _launch_rules(mesh)
     shards = rules.axes_size(rules.dp_axes)
     spec = rules.activation_spec("launch", (shards,) + (1,) * (ndim - 1))
@@ -435,177 +428,92 @@ def _info(cycles: int, stats, steps: int, cfg: GGPUConfig) -> dict:
 Region = Optional[Tuple[int, int]]
 
 
-# -- device-resident chaining (patches) --------------------------------------
-#
-# A *patch* overwrites a region of a launch's staged memory with a device
-# array — typically another launch's ``device_mem``/``device_mem_block``
-# output — so a consumer kernel reads its producer's result without any
-# host transfer. Patches are applied to the freshly staged buffer BEFORE
-# the jitted stepper consumes (and donates) it, so they change neither the
-# compiled envelope nor the donation discipline. Two forms:
-#
-#   * per-launch: a sequence with one entry per launch, each ``None`` or a
-#     list of ``(dst_lo, dst_hi, src_array)`` tuples (an optional fourth
-#     element ``"xor"`` flips bits instead of overwriting — the SEU
-#     injection form, see ``repro.faults``);
-#   * ``BlockPatch(lo, hi, block)``: one uniform region for every real
-#     launch of the chunk, ``block`` row ``j`` feeding launch ``j`` — a
-#     single fused device op, the chunk-to-chunk fast path;
-#   * ``XorBlockPatch(lo, hi, block)``: same shape contract, but XORed
-#     into the staged words rather than overwriting them. A zero row is a
-#     no-op, so a chunk-wide SEU plan stays one fused dispatch even when
-#     only a few launches are hit (bit-exact off-by-default: injection
-#     disabled means the patch is simply absent, not an identity op).
-
-
-class BlockPatch(NamedTuple):
-    """One uniform staged-memory patch across all ``B`` real launches of a
-    chunk: ``block`` is ``(B, hi - lo)``; row ``j`` overwrites launch
-    ``j``'s words ``[lo, hi)``."""
+class Patch(NamedTuple):
+    """Words ``[lo, hi)`` of some launches' staged memory, overwritten
+    (``op="set"``) or XORed (``op="xor"``: the single-event-upset form; a
+    zero row is a no-op) before the stepper runs. ``block`` is
+    ``(len(rows), hi - lo)``, row ``k`` feeding launch ``rows[k]``;
+    ``rows=None`` means every launch of the call, in order."""
     lo: int
     hi: int
-    block: jax.Array
+    block: Any
+    rows: Optional[Sequence[int]] = None
+    op: str = "set"
 
 
-class XorBlockPatch(NamedTuple):
-    """Like :class:`BlockPatch` but ``block`` row ``j`` is XORed into
-    launch ``j``'s words ``[lo, hi)`` — the fused single-event-upset
-    (bit-flip) injection primitive. Rows of zeros leave their launch
-    untouched."""
-    lo: int
-    hi: int
-    block: jax.Array
+def _check_patches(patches: Sequence[Patch], sizes: Sequence[int]):
+    """Validate each patch against every target launch's own memory
+    size (not a padded envelope)."""
+    B = len(sizes)
+    for p in patches:
+        if p.op not in ("set", "xor"):
+            raise ValueError(f"patch op must be 'set' or 'xor', "
+                             f"got {p.op!r}")
+        rows = range(B) if p.rows is None else [int(r) for r in p.rows]
+        if len(set(rows)) != len(rows) \
+                or not all(0 <= r < B for r in rows):
+            raise ValueError(f"patch rows {p.rows} must be distinct "
+                             f"launch indices in [0, {B})")
+        if not all(0 <= p.lo <= p.hi <= sizes[r] for r in rows):
+            raise ValueError(f"patch [{p.lo}, {p.hi}) outside a target "
+                             f"launch's memory image (sizes {list(sizes)})")
+        if tuple(np.shape(p.block)) != (len(rows), p.hi - p.lo):
+            raise ValueError(f"patch [{p.lo}, {p.hi}) on {len(rows)} "
+                             f"launch(es) expects a block of shape "
+                             f"{(len(rows), p.hi - p.lo)}, got "
+                             f"{tuple(np.shape(p.block))}")
 
 
-def _check_patches(patches, B: int, sizes: Sequence[int]):
-    """Validate patch bounds against each launch's own memory size."""
-    if isinstance(patches, (BlockPatch, XorBlockPatch)):
-        lo, hi, block = patches
-        if not all(0 <= lo <= hi <= s for s in sizes[:B]):
-            raise ValueError(f"block patch [{lo}, {hi}) outside a launch's "
-                             f"memory image (sizes {list(sizes[:B])})")
-        if tuple(block.shape) != (B, hi - lo):
-            raise ValueError(f"block patch expects shape {(B, hi - lo)}, "
-                             f"got {tuple(block.shape)}")
-        return
-    patches = list(patches)
-    if len(patches) != B:
-        raise ValueError(f"patches has {len(patches)} entries for "
-                         f"{B} launches")
-    for plist, size in zip(patches, sizes):
-        for entry in (plist or ()):
-            lo, hi, src = entry[0], entry[1], entry[2]
-            if len(entry) > 3 and entry[3] not in ("set", "xor"):
-                raise ValueError(f"patch op must be 'set' or 'xor', "
-                                 f"got {entry[3]!r}")
-            if not (0 <= lo <= hi <= size):
-                raise ValueError(f"patch [{lo}, {hi}) outside memory "
-                                 f"image [0, {size})")
-            if np.shape(src) != (hi - lo,):
-                raise ValueError(f"patch [{lo}, {hi}) expects "
-                                 f"{hi - lo} words, got {np.shape(src)}")
+def _elements(mem, msize: int):
+    """The launch elements of a staged or final memory (device or host
+    array) on its row view (module doc), ``(S*b, msize)`` with the write
+    sinks left out. A flat cohort's one row stays 1-D (``S = 1``
+    implied): on the TPU, reshaping the whole image into one 2-D row
+    would relay all of it out."""
+    return mem[..., :-1].reshape(-1, msize)
 
 
-@functools.partial(jax.jit, static_argnames=("lo", "hi"),
+@functools.partial(jax.jit, static_argnames=("msize", "lo", "hi", "op"),
                    donate_argnums=(0,))
-def _patch_rows_block(body, block, lo, hi):
-    """Jitted ``BlockPatch`` application to a row-per-launch staging
-    buffer: one compiled dispatch (donating the staging buffer) instead
-    of a handful of eager ops — the patch cost is fixed per chunk, so it
-    must not scale the pipelined path's dispatch overhead."""
-    return body.at[:block.shape[0], lo:hi].set(block)
-
-
-@functools.partial(jax.jit, static_argnames=("msize", "lo", "hi"),
-                   donate_argnums=(0,))
-def _patch_flat_block(staged, block, msize, lo, hi):
-    """Jitted ``BlockPatch`` application to a flat cohort/single staging
-    buffer (reshape + patch + reflatten fused into one dispatch)."""
-    rows = (staged.shape[0] - 1) // msize
-    body = staged[:rows * msize].reshape(rows, msize)
-    body = body.at[:block.shape[0], lo:hi].set(block)
-    return jnp.concatenate([body.reshape(-1), staged[rows * msize:]])
-
-
-@functools.partial(jax.jit, static_argnames=("lo", "hi"),
-                   donate_argnums=(0,))
-def _xor_rows_block(body, block, lo, hi):
-    """Jitted ``XorBlockPatch`` application to a row-per-launch staging
-    buffer: bit-flips land as one compiled dispatch, same cost profile as
-    the dependency-feed ``BlockPatch`` fast path."""
-    region = body[:block.shape[0], lo:hi]
-    return body.at[:block.shape[0], lo:hi].set(region ^ block)
-
-
-@functools.partial(jax.jit, static_argnames=("msize", "lo", "hi"),
-                   donate_argnums=(0,))
-def _xor_flat_block(staged, block, msize, lo, hi):
-    """Jitted ``XorBlockPatch`` application to a flat cohort/single
-    staging buffer."""
-    rows = (staged.shape[0] - 1) // msize
-    body = staged[:rows * msize].reshape(rows, msize)
-    region = body[:block.shape[0], lo:hi]
-    body = body.at[:block.shape[0], lo:hi].set(region ^ block)
-    return jnp.concatenate([body.reshape(-1), staged[rows * msize:]])
-
-
-def _patch_rows(body: jax.Array, patches) -> jax.Array:
-    """Apply patches to a row-per-launch view of the staged memory."""
-    if isinstance(patches, XorBlockPatch):
-        lo, hi, block = patches
-        return _xor_rows_block(body, block, lo=lo, hi=hi)
-    if isinstance(patches, BlockPatch):
-        lo, hi, block = patches
-        return _patch_rows_block(body, block, lo=lo, hi=hi)
-    for i, plist in enumerate(patches):
-        for entry in (plist or ()):
-            lo, hi, src = entry[0], entry[1], entry[2]
-            if len(entry) > 3 and entry[3] == "xor":
-                body = body.at[i, lo:hi].set(body[i, lo:hi] ^ src)
-            else:
-                body = body.at[i, lo:hi].set(src)
-    return body
-
-
-def _patch_flat(staged: jax.Array, msize: int, patches) -> jax.Array:
-    """Patch a flat ``(rows*msize + 1,)`` cohort/single staging buffer.
-    Padding rows (copies of the first image) stay unpatched — they are
-    sliced away at resolution and each launch is isolated, so they are
-    never observable."""
-    if isinstance(patches, XorBlockPatch):
-        lo, hi, block = patches
-        return _xor_flat_block(staged, block, msize=msize, lo=lo, hi=hi)
-    if isinstance(patches, BlockPatch):
-        lo, hi, block = patches
-        return _patch_flat_block(staged, block, msize=msize, lo=lo, hi=hi)
-    rows = (staged.shape[0] - 1) // msize
-    body = staged[:rows * msize].reshape(rows, msize)
-    body = _patch_rows(body, patches)
-    return jnp.concatenate([body.reshape(-1), staged[rows * msize:]])
+def _apply_patch(staged, block, rows, msize, lo, hi, op):
+    """One patch on the row view of a staged buffer, as one compiled
+    dispatch that donates the buffer: padding elements are never
+    targeted. ``rows`` is a device index array (``None``: the first
+    ``len(block)`` launches), so a new row set re-uses the compiled
+    applier."""
+    body = _elements(staged, msize)
+    at = slice(0, block.shape[0]) if rows is None else rows
+    if op == "xor":
+        block = body[at, lo:hi] ^ block
+    body = body.at[at, lo:hi].set(block)
+    return staged.at[..., :-1].set(body.reshape(staged[..., :-1].shape))
 
 
 @functools.partial(jax.jit, static_argnames=("B", "msize", "lo", "hi"))
-def _slice_block(mem, B, msize, lo, hi):
-    """All launches' [lo, hi) regions of a flat cohort/single memory as one
-    fused (B, hi-lo) device computation — one dispatch per chunk."""
-    return mem[:B * msize].reshape(B, msize)[:, lo:hi]
+def _slice_rows(mem, B, msize, lo, hi):
+    """All ``B`` launches' ``[lo, hi)`` regions of a final memory as one
+    fused ``(B, hi - lo)`` device computation — padding elements beyond
+    ``B`` are dropped."""
+    return _elements(mem, msize)[:B, lo:hi]
 
 
-@functools.partial(jax.jit, static_argnames=("lo", "hi"))
-def _slice_batch(mem, lo, hi):
-    """All launches' [lo, hi) regions of a batched (N, M+1) memory."""
-    return mem[:, lo:hi]
-
-
-@functools.partial(jax.jit, static_argnames=("B", "msize", "lo", "hi"))
-def _slice_rows(mem_rows, B, msize, lo, hi):
-    """All launches' [lo, hi) regions of a sharded-cohort memory
-    (``(shards, B_local*msize + 1)`` device rows) as one fused device
-    computation — padding rows beyond ``B`` are dropped."""
-    shards = mem_rows.shape[0]
-    b_local = (mem_rows.shape[1] - 1) // msize
-    flat = mem_rows[:, :b_local * msize].reshape(shards * b_local, msize)
-    return flat[:B, lo:hi]
+def _stage(host: np.ndarray, patches: Sequence[Patch], msize: int,
+           sharding) -> jax.Array:
+    """Upload a host-staged image (placed by ``sharding`` when sharded)
+    and apply ``patches`` to it on the device."""
+    staged = jnp.asarray(host) if sharding is None \
+        else jax.device_put(host, sharding)
+    for p in patches:
+        rows = None if p.rows is None \
+            else jnp.asarray(np.asarray(p.rows, np.int32))
+        staged = _apply_patch(staged, p.block, rows, msize=msize,
+                              lo=p.lo, hi=p.hi, op=p.op)
+    if patches and sharding is not None:
+        # the applier's output placement is the compiler's choice: restore
+        # the launch layout (this reshard is what moves a producer's
+        # output to its consumer's shard — still no host hop)
+        staged = jax.device_put(staged, sharding)
+    return staged
 
 
 def _check_regions(regions: Optional[Sequence[Region]], B: int,
@@ -632,45 +540,44 @@ def _check_regions(regions: Optional[Sequence[Region]], B: int,
 
 
 class LaunchHandle:
-    """Future for one in-flight (possibly folded) kernel launch.
+    """Future for one in-flight launch call.
 
-    ``wait()`` blocks until the device retires the launch, fetching only
+    ``wait()`` blocks until the device retires the call, fetching only
     the tiny ``done/cycles/stats/step`` arrays, and raises
     ``KernelLaunchError`` (with the failing position in ``index``) when a
     launch hit ``max_steps``. The final memory stays device-resident until
     asked for: ``mem(i)`` downloads launch ``i``'s image — the declared
     ``out_region`` slice when one was given (``(0, 0)``: no transfer at
-    all), the full image otherwise. ``results()`` returns the same
-    ``(mem, info)`` pairs as the sync entry point, bit-exact.
+    all), the full image otherwise. ``results()`` returns the ``(mem,
+    info)`` pairs ``run_kernel`` returns, bit-exact.
+
+    The final memory is read through the one row view of the module doc:
+    ``S`` staging rows of ``rows // S`` elements of ``msize`` words, of
+    which the first ``B`` are real launches (``sizes`` their own memory
+    sizes) and the rest padding that no method exposes.
 
     ``donated`` is the staged device buffer the dispatch consumed; XLA
     invalidates it at dispatch (the final memory aliases it), and the
     handle never reads it — tests assert ``donated.is_deleted()``.
-
-    Sharded dispatches (``mesh=``) pad the launch axis up to the shard
-    count: ``rows`` is the padded element count, ``B`` stays the real
-    one, and every resolution path slices the padding away before it can
-    be observed. Kind ``"shard-cohort"`` additionally remembers that the
-    final memory is laid out as per-shard rows rather than one flat
-    image.
+    ``envelope`` is the static signature the engine jitted for this call
+    (path, padded rows, wavefronts, program length, memory size, opcode
+    set): a call with an envelope seen before re-uses a compiled stepper.
     """
 
-    def __init__(self, final: MachineState, cfg: GGPUConfig, kind: str,
-                 B: int, msize: int, n_keep: Optional[Sequence[int]],
-                 regions: Optional[Sequence[Region]], batch_size:
-                 Optional[int], donated, rows: Optional[int] = None):
+    def __init__(self, final: MachineState, cfg: GGPUConfig, B: int, S: int,
+                 rows: int, msize: int, sizes: Sequence[int],
+                 regions: Optional[Sequence[Region]], donated,
+                 envelope: tuple):
         self._final = final
         self._cfg = cfg
-        self._kind = kind
         self._B = B
-        self._rows = B if rows is None else rows
+        self._rows = rows
+        self._b = rows // S
         self._msize = msize
-        self._n_keep = list(n_keep) if n_keep is not None else None
-        self._regions = _check_regions(
-            regions, B, self._n_keep if self._n_keep is not None
-            else [msize] * B)
-        self._batch_size = batch_size
+        self._sizes = list(sizes)
+        self._regions = _check_regions(regions, B, self._sizes)
         self.donated = donated
+        self.envelope = envelope
         self._small = None                     # (cycles, stats, steps)
         self._mem_full = None
         self._mems: dict = {}
@@ -695,26 +602,17 @@ class LaunchHandle:
         Raises ``KernelLaunchError`` naming the first failing launch."""
         if self._small is not None:
             return self
-        f = self._final
-        # padding elements (rows > B) are sliced away before inspection:
-        # a sharded dispatch's fillers are never observable, including in
-        # the failure path
-        done = np.asarray(f.done).reshape(self._rows, -1)[:self._B]
-        if self._kind == "batch":
-            cycles = np.asarray(f.cycles)[:self._B, 0]
-            stats = np.asarray(f.stats)[:self._B, 0]
-            steps = np.asarray(f.step)[:self._B, 0]
-        else:
-            cycles = np.asarray(f.cycles)[:self._B]
-            stats = np.asarray(f.stats)[:self._B]
-            steps = np.asarray(f.step)[:self._B]
-        for i in range(self._B):
+        f, R, B = self._final, self._rows, self._B
+        # padding elements (rows > B) are sliced away before inspection,
+        # including in the failure path
+        done = np.asarray(f.done).reshape(R, -1)[:B]
+        cycles = np.asarray(f.cycles).reshape(R)[:B]
+        stats = np.asarray(f.stats).reshape(R, 4)[:B]
+        steps = np.asarray(f.step).reshape(R)[:B]
+        for i in range(B):
             if not done[i].all():
-                what = {"single": "kernel", "cohort": f"cohort kernel {i}",
-                        "shard-cohort": f"cohort kernel {i}",
-                        "batch": f"batched kernel {i}"}[self._kind]
                 raise KernelLaunchError(
-                    f"{what} hit max_steps without halting", i)
+                    f"kernel {i} hit max_steps without halting", i)
         self._small = (cycles, stats, steps)
         return self
 
@@ -723,8 +621,7 @@ class LaunchHandle:
     def info(self, i: int = 0) -> dict:
         cycles, stats, steps = self.wait()._small
         info = _info(int(cycles[i]), stats[i], int(steps[i]), self._cfg)
-        if self._batch_size is not None:
-            info["batch_size"] = self._batch_size
+        info["batch_size"] = self._B
         return info
 
     def infos(self) -> List[dict]:
@@ -734,10 +631,9 @@ class LaunchHandle:
         """Launch ``i``'s final memory: the declared region slice when one
         was given, the full image otherwise (downloaded once, cached).
 
-        Same-kernel chunks declare the same region for every launch, so
-        the uniform case collapses all downloads into **one** fused device
-        slice per chunk (``_slice_block``) instead of one dispatch per
-        launch."""
+        When every launch declares the same region, all downloads collapse
+        into **one** fused device slice per call (``device_mem_block``)
+        instead of one dispatch per launch."""
         region = self._regions[i] if self._regions is not None else None
         if region is None:
             return self._full_mem(i)
@@ -746,43 +642,18 @@ class LaunchHandle:
             if hi <= lo:
                 self._mems[i] = np.zeros(0, np.int32)
             elif all(r == region for r in self._regions):
-                if self._kind == "batch":
-                    block = np.asarray(_slice_batch(self._final.mem, lo, hi))
-                elif self._kind == "shard-cohort":
-                    block = np.asarray(_slice_rows(
-                        self._final.mem, self._B, self._msize, lo, hi))
-                else:
-                    block = np.asarray(_slice_block(
-                        self._final.mem, self._B, self._msize, lo, hi))
+                block = np.asarray(self.device_mem_block(lo, hi))
                 for j in range(self._B):
                     self._mems[j] = block[j]
-            elif self._kind == "batch":
-                self._mems[i] = np.asarray(self._final.mem[i, lo:hi])
-            elif self._kind == "shard-cohort":
-                b_local = (self._final.mem.shape[1] - 1) // self._msize
-                shard, slot = divmod(i, b_local)
-                base = slot * self._msize
-                self._mems[i] = np.asarray(
-                    self._final.mem[shard, base + lo:base + hi])
             else:
-                base = i * self._msize
-                self._mems[i] = np.asarray(
-                    self._final.mem[base + lo:base + hi])
+                self._mems[i] = np.asarray(self.device_mem(i, region))
         return self._mems[i]
 
     def _full_mem(self, i: int) -> np.ndarray:
         if self._mem_full is None:
-            m = np.asarray(self._final.mem)
-            if self._kind == "batch":
-                self._mem_full = m[:, :-1]
-            elif self._kind == "shard-cohort":
-                # per-shard rows: drop each row's write sink, flatten the
-                # shard axis back into one element-major image stack
-                self._mem_full = m[:, :-1].reshape(-1, self._msize)
-            else:
-                self._mem_full = m[:-1].reshape(self._rows, self._msize)
-        row = self._mem_full[i]
-        return row[:self._n_keep[i]] if self._n_keep is not None else row
+            self._mem_full = _elements(np.asarray(self._final.mem),
+                                       self._msize)
+        return self._mem_full[i][:self._sizes[i]]
 
     # -- device-resident access (no host transfer) ---------------------------
 
@@ -792,39 +663,25 @@ class LaunchHandle:
         device-resident array (default: the full image). Never blocks and
         never touches the host — this is the producer side of the
         device-resident chaining protocol: feed the result straight into a
-        consumer launch's ``patches``. The returned array only *reads*
-        the final memory (donation is unaffected), and XLA sequences it
-        after the producing dispatch, so no explicit wait is needed."""
-        if region is None:
-            size = (self._n_keep[i] if self._n_keep is not None
-                    else self._msize)
-            region = (0, size)
-        lo, hi = region
-        if self._kind == "batch":
-            return self._final.mem[i, lo:hi]
-        if self._kind == "shard-cohort":
-            b_local = (self._final.mem.shape[1] - 1) // self._msize
-            shard, slot = divmod(i, b_local)
-            base = slot * self._msize
-            return self._final.mem[shard, base + lo:base + hi]
-        base = i * self._msize
-        return self._final.mem[base + lo:base + hi]
+        consumer launch's ``Patch``. The returned array only *reads* the
+        final memory (donation is unaffected), and XLA sequences it after
+        the producing dispatch, so no explicit wait is needed."""
+        lo, hi = (0, self._sizes[i]) if region is None else region
+        row, slot = divmod(i, self._b)
+        base = slot * self._msize
+        # slice first: only the slice, never the whole image, is viewed as
+        # the (S, hi - lo) rows its launch's row is taken from
+        return jnp.atleast_2d(self._final.mem[..., base + lo:base + hi])[row]
 
     def device_mem_block(self, lo: int, hi: int) -> jax.Array:
         """All ``B`` launches' ``[lo, hi)`` slices as one device-resident
-        ``(B, hi - lo)`` array — one fused device op per chunk, the fast
-        path for feeding a whole producer chunk into a consumer chunk's
-        ``BlockPatch``. Never blocks, never touches the host."""
-        if self._kind == "batch":
-            return _slice_batch(self._final.mem, lo, hi)[:self._B]
-        if self._kind == "shard-cohort":
-            return _slice_rows(self._final.mem, self._B, self._msize,
-                               lo, hi)
-        return _slice_block(self._final.mem, self._B, self._msize, lo, hi)
+        ``(B, hi - lo)`` array — one fused device op per call, the fast
+        path for feeding a whole producer call into a consumer call's
+        ``Patch``. Never blocks, never touches the host."""
+        return _slice_rows(self._final.mem, self._B, self._msize, lo, hi)
 
     def results(self) -> List[Tuple[np.ndarray, dict]]:
-        """All launches as (mem, info) pairs — exactly what the sync entry
-        point returns."""
+        """All launches as (mem, info) pairs, in call order."""
         return [(self.mem(i), self.info(i)) for i in range(self._B)]
 
     def result(self) -> Tuple[np.ndarray, dict]:
@@ -835,211 +692,93 @@ class LaunchHandle:
         return self.mem(0), self.info(0)
 
 
-def _stage(mems: Sequence[np.ndarray]) -> jax.Array:
-    """Host-copy the image(s) plus the write-sink slot into one fresh
-    device buffer — the buffer the jitted wrapper donates."""
-    return jnp.asarray(np.concatenate(list(mems)
-                                      + [np.zeros(1, np.int32)]))
-
-
-def run_kernel_async(prog: np.ndarray, mem0: np.ndarray, n_items: int,
-                     cfg: GGPUConfig, *, out_region: Region = None,
-                     patches=None, legacy: bool = False) -> LaunchHandle:
-    """Dispatch a single launch asynchronously; returns a ``LaunchHandle``
-    while the device still runs. ``out_region=(lo, hi)`` limits the
-    eventual memory download to that slice of the final image. ``patches``
-    optionally overwrites regions of the staged memory with device arrays
-    before dispatch (a flat list of ``(lo, hi, src)`` — the single-launch
-    form of the chunk-level patch protocol above)."""
-    prog = np.asarray(prog, np.int32)
-    mem0 = np.asarray(mem0, np.int32)
-    staged = _stage([mem0])
-    if patches is not None:
-        msize = mem0.shape[0]
-        per_launch = (patches
-                      if isinstance(patches, (BlockPatch, XorBlockPatch))
-                      else [list(patches)])
-        _check_patches(per_launch, 1, [msize])
-        staged = _patch_flat(staged, msize, per_launch)
-    final = _run_single(
-        jnp.asarray(prog), staged,
-        jnp.asarray(int(n_items), jnp.int32), cfg,
-        _n_wavefronts(int(n_items), cfg), int(prog.shape[0]),
-        None if legacy else _static_ops(prog), legacy)
-    return LaunchHandle(final, cfg, "single", 1, mem0.shape[0], None,
-                        [out_region] if out_region is not None else None,
-                        None, staged)
-
-
-def run_kernel(prog: np.ndarray, mem0: np.ndarray, n_items: int,
-               cfg: GGPUConfig, *, legacy: bool = False):
-    """Execute a kernel. Returns (mem_final, info dict).
-
-    ``legacy=True`` runs the seed-faithful reference stepper (identical
-    results and cycles, pre-refactor wall-clock) for differential testing
-    and as the baseline of ``benchmarks.engine_bench``."""
-    return run_kernel_async(prog, mem0, n_items, cfg,
-                            legacy=legacy).result()
-
-
-def run_kernel_cohort_async(prog: np.ndarray, mems: Sequence[np.ndarray],
-                            n_items: int, cfg: GGPUConfig, *,
-                            out_regions: Optional[Sequence[Region]] = None,
-                            patches=None, mesh=None) -> LaunchHandle:
-    """Dispatch B same-kernel launches as one folded stepper call,
-    asynchronously. ``out_regions`` optionally declares one download slice
-    per launch (``None`` entries download that launch's full image).
-    ``patches`` optionally overwrites regions of the staged memory with
-    device arrays before dispatch — a ``BlockPatch`` or one
-    ``[(lo, hi, src), ...]`` list per launch (see the patch protocol
-    above). ``mesh`` shards the launch axis across the mesh's
-    data-parallel devices (see module doc); a 1-extent mesh falls back to
-    the single-device path."""
-    prog = np.asarray(prog, np.int32)
-    mems = [np.asarray(m, np.int32) for m in mems]
-    if not mems:
-        raise ValueError("empty cohort")
-    msize = mems[0].shape[0]
-    if any(m.shape[0] != msize for m in mems):
-        raise ValueError("cohort memory images must share one shape")
-    B = len(mems)
-    if patches is not None:
-        _check_patches(patches, B, [msize] * B)
-    shards = launch_shards(mesh)
-    if shards > 1 and B > 1:
-        return _dispatch_cohort_sharded(prog, mems, n_items, cfg, mesh,
-                                        shards, out_regions, patches)
-    rows = cohort_rows(B)
-    staged = _stage(mems + [mems[0]] * (rows - B))
-    if patches is not None:
-        staged = _patch_flat(staged, msize, patches)
-    final = _run_cohort(
-        jnp.asarray(prog), staged,
-        jnp.asarray(int(n_items), jnp.int32), cfg, rows,
-        _n_wavefronts(int(n_items), cfg), int(prog.shape[0]),
-        _static_ops(prog))
-    return LaunchHandle(final, cfg, "cohort", B, msize, None, out_regions,
-                        B, staged, rows=rows)
-
-
-def _dispatch_cohort_sharded(prog, mems, n_items, cfg, mesh, shards,
-                             out_regions, patches=None) -> LaunchHandle:
-    """Shard a cohort's launch axis over ``mesh``: pad B up to the
-    ``cohort_rows`` bucket with copies of the first image (same kernel,
-    same halt behavior — sliced away at resolution), stage one memory row
-    per shard (its slice of the images plus a private write sink), and
-    dispatch the shard_map'd stepper once."""
-    B, msize = len(mems), mems[0].shape[0]
-    n_rows = cohort_rows(B, shards)
-    padded = mems + [mems[0]] * (n_rows - B)
-    b_local = n_rows // shards
-    rows = np.stack([
-        np.concatenate(padded[s * b_local:(s + 1) * b_local]
-                       + [np.zeros(1, np.int32)])
-        for s in range(shards)])
-    staged = jax.device_put(rows, _launch_sharding(mesh, 2))
-    if patches is not None:
-        # patch the element-major row view, then restore the per-shard
-        # row layout + sharding (the resulting reshard is what moves a
-        # producer's output to its consumer's shard — still no host hop)
-        body = staged[:, :b_local * msize].reshape(n_rows, msize)
-        body = _patch_rows(body, patches)
-        staged = jax.device_put(
-            jnp.concatenate([body.reshape(shards, b_local * msize),
-                             staged[:, b_local * msize:]], axis=1),
-            _launch_sharding(mesh, 2))
-    final = _sharded_cohort_fn(
-        cfg, b_local, _n_wavefronts(int(n_items), cfg),
-        int(prog.shape[0]), msize, _static_ops(prog), mesh)(
-        jnp.asarray(prog), staged, jnp.asarray(int(n_items), jnp.int32))
-    return LaunchHandle(final, cfg, "shard-cohort", B, msize, None,
-                        out_regions, B, staged, rows=n_rows)
-
-
-def run_kernel_cohort(prog: np.ndarray, mems: Sequence[np.ndarray],
-                      n_items: int, cfg: GGPUConfig
-                      ) -> List[Tuple[np.ndarray, dict]]:
-    """Execute the same kernel over B memory images as one folded stepper
-    call (B*W wavefronts, per-element accounting). Bit-exact per launch."""
-    mems = list(mems)                # materialize once: iterators welcome
-    if not mems:
-        return []
-    return run_kernel_cohort_async(prog, mems, n_items, cfg).results()
-
-
-def run_kernel_batch_async(progs: Sequence[np.ndarray],
-                           mems: Sequence[np.ndarray],
-                           n_items: Sequence[int], cfg: GGPUConfig, *,
-                           out_regions: Optional[Sequence[Region]] = None,
-                           patches=None, mesh=None) -> LaunchHandle:
-    """Dispatch N heterogeneous launches as one vmapped stepper call,
-    asynchronously (padding exactly as ``run_kernel_batch``). ``patches``
-    optionally overwrites regions of the staged memory with device arrays
-    before dispatch (see the patch protocol above; bounds check against
-    each launch's own memory size, not the padded envelope). ``mesh``
-    shards the vmapped launch axis across the mesh's data-parallel
-    devices, padding N up to the shard count with trivial 1-item HALT
-    fillers (invisible at resolution); a 1-extent mesh falls back to the
-    single-device path."""
-    if not (len(progs) == len(mems) == len(n_items)):
-        raise ValueError("progs, mems, n_items must have equal length")
-    if not progs:
-        raise ValueError("empty batch")
+def launch(progs: Sequence[np.ndarray], mems: Sequence[np.ndarray],
+           n_items: Sequence[int], cfg: GGPUConfig, *,
+           out_regions: Optional[Sequence[Region]] = None,
+           patches: Sequence[Patch] = (), mesh=None,
+           legacy: bool = False) -> LaunchHandle:
+    """Dispatch launches ``i`` = (``progs[i]``, ``mems[i]``,
+    ``n_items[i]``) as one stepper call, asynchronously: a cohort when they
+    all share one kernel, a vmapped batch otherwise, sharded over ``mesh``
+    when it has more than one data-parallel shard and there is more than
+    one launch (module doc). ``out_regions`` optionally declares one
+    download slice per launch (``None`` entries download that launch's
+    full image); ``patches`` are applied in order to the staged memory
+    before dispatch. ``legacy=True`` runs the seed-faithful reference
+    stepper, one launch only."""
     progs = [np.asarray(p, np.int32) for p in progs]
     mems = [np.asarray(m, np.int32) for m in mems]
     n_items = [int(n) for n in n_items]
     B = len(progs)
-    if patches is not None:
-        _check_patches(patches, B, [m.shape[0] for m in mems])
-    shards = launch_shards(mesh)
-    pad = -B % shards if shards > 1 and B > 1 else 0
-    if pad:
-        width = progs[0].shape[1]
-        progs = progs + [np.zeros((1, width), np.int32)] * pad  # HALT
-        mems = mems + [np.zeros(1, np.int32)] * pad
-        n_items = n_items + [1] * pad
-    P = max(p.shape[0] for p in progs)
-    M = max(m.shape[0] for m in mems)
-    prog_b = np.stack([np.pad(p, ((0, P - p.shape[0]), (0, 0)))
-                       for p in progs])                  # HALT == all-zeros
-    # each row zero-padded to the envelope plus its own write-sink slot
-    mem_b = np.stack([np.pad(m, (0, M + 1 - m.shape[0])) for m in mems])
-    W = max(_n_wavefronts(int(n), cfg) for n in n_items)
-    ops = tuple(sorted(set().union(*(_static_ops(p) for p in progs))))
-    n_arr = jnp.asarray(np.asarray(n_items, np.int32))
-    msz_arr = jnp.asarray(np.array([m.shape[0] for m in mems], np.int32))
-    if shards > 1 and B > 1:
-        sharding = _launch_sharding(mesh, 2)
-        staged = jax.device_put(mem_b, sharding)
-        if patches is not None:
-            # batch rows are already row-per-launch; patch then reshard
-            staged = jax.device_put(_patch_rows(staged, patches), sharding)
-        final = _sharded_batch_fn(cfg, W, P, M, ops, mesh)(
-            jnp.asarray(prog_b), staged, n_arr, msz_arr)
+    if not B or len(mems) != B or len(n_items) != B:
+        raise ValueError(f"launch needs one mem and one n_items per "
+                         f"program, at least one launch: got {B} progs, "
+                         f"{len(mems)} mems, {len(n_items)} n_items")
+    if legacy and B > 1:
+        raise ValueError("the legacy reference stepper runs one launch "
+                         "per call")
+    sizes = [m.shape[0] for m in mems]
+    patches = list(patches)
+    _check_patches(patches, sizes)
+    shards = launch_shards(mesh) if B > 1 else 1
+    sharding = _launch_sharding(mesh, 2) if shards > 1 else None
+    if (len(set(n_items)) == 1 and len(set(sizes)) == 1
+            and all(np.array_equal(p, progs[0]) for p in progs[1:])):
+        prog, n, msize = progs[0], n_items[0], sizes[0]
+        rows = cohort_rows(B, shards)
+        b = rows // shards
+        padded = mems + [mems[0]] * (rows - B)
+        host = [np.concatenate(padded[s * b:(s + 1) * b]
+                               + [np.zeros(1, np.int32)])
+                for s in range(shards)]
+        staged = _stage(host[0] if shards == 1 else np.stack(host),
+                        patches, msize, sharding)
+        W, P = _n_wavefronts(n, cfg), prog.shape[0]
+        ops = None if legacy else _static_ops(prog)
+        n_arr = jnp.asarray(n, jnp.int32)
+        if shards > 1:
+            final = _sharded_cohort_fn(cfg, b, W, P, msize, ops, mesh)(
+                jnp.asarray(prog), staged, n_arr)
+        else:
+            final = _run_cohort(jnp.asarray(prog), staged, n_arr, cfg, rows,
+                                W, P, ops, legacy)
+        envelope = ("cohort", rows, W, P, msize, ops, legacy)
+        S = shards
     else:
-        staged = jnp.asarray(mem_b)
-        if patches is not None:
-            staged = _patch_rows(staged, patches)
-        final = _run_batch(jnp.asarray(prog_b), staged, n_arr, msz_arr,
-                           cfg, W, P, ops)
-    return LaunchHandle(final, cfg, "batch", B, M,
-                        [m.shape[0] for m in mems[:B]], out_regions,
-                        B, staged, rows=B + pad)
+        pad = -B % shards
+        if pad:                                # 1-item HALT fillers
+            progs = progs + [np.zeros((1, progs[0].shape[1]), np.int32)] * pad
+            mems = mems + [np.zeros(1, np.int32)] * pad
+            n_items = n_items + [1] * pad
+        P = max(p.shape[0] for p in progs)
+        msize = max(m.shape[0] for m in mems)
+        prog_b = np.stack([np.pad(p, ((0, P - p.shape[0]), (0, 0)))
+                           for p in progs])              # HALT == all-zeros
+        # each row zero-padded to the envelope plus its own write-sink slot
+        mem_b = np.stack([np.pad(m, (0, msize + 1 - m.shape[0]))
+                          for m in mems])
+        rows = S = B + pad
+        staged = _stage(mem_b, patches, msize, sharding)
+        W = max(_n_wavefronts(k, cfg) for k in n_items)
+        ops = tuple(sorted(set().union(*(_static_ops(p) for p in progs))))
+        n_arr = jnp.asarray(np.asarray(n_items, np.int32))
+        msz_arr = jnp.asarray(np.array([m.shape[0] for m in mems], np.int32))
+        if shards > 1:
+            final = _sharded_batch_fn(cfg, W, P, msize, ops, mesh)(
+                jnp.asarray(prog_b), staged, n_arr, msz_arr)
+        else:
+            final = _run_batch(jnp.asarray(prog_b), staged, n_arr, msz_arr,
+                               cfg, W, P, ops)
+        envelope = ("batch", rows, W, P, msize, ops)
+    return LaunchHandle(final, cfg, B, S, rows, msize, sizes, out_regions,
+                        staged, envelope)
 
 
-def run_kernel_batch(progs: Sequence[np.ndarray],
-                     mems: Sequence[np.ndarray],
-                     n_items: Sequence[int],
-                     cfg: GGPUConfig) -> List[Tuple[np.ndarray, dict]]:
-    """Execute N heterogeneous kernel launches as one vmapped stepper call.
+def run_kernel(prog: np.ndarray, mem0: np.ndarray, n_items: int,
+               cfg: GGPUConfig, *, legacy: bool = False):
+    """Execute one kernel launch; returns (mem_final, info dict).
 
-    Programs are padded to a common length with HALT words and memory
-    images zero-padded to a common size; per-launch results and cycle
-    counts are exact (the padding is invisible to the machine — each
-    launch's address clip still binds at its own memory size). Returns a
-    list of (mem_final, info) in submission order."""
-    progs = list(progs)              # materialize once: iterators welcome
-    if not progs:
-        return []
-    return run_kernel_batch_async(progs, list(mems), list(n_items),
-                                  cfg).results()
+    ``legacy=True`` runs the seed-faithful reference stepper (identical
+    results and cycles, pre-refactor wall-clock) for differential testing
+    and as the baseline of ``benchmarks.engine_bench``."""
+    return launch([prog], [mem0], [n_items], cfg, legacy=legacy).result()

@@ -1,13 +1,15 @@
 """Compiled-executor layer: runs planned chunks on one device config.
 
-An ``Executor`` owns the engine entry points for one ``GGPUConfig`` and
-tracks the **envelope cache**: the set of compiled-stepper signatures
-(chunk kind, batch size, wavefront count, program length, memory size,
-opcode set) this process has already traced. The jit cache inside
-``repro.ggpu.engine`` is keyed on exactly these statics, so a chunk whose
-envelope has been seen re-uses the compiled stepper — repeat serving
-traffic never re-traces — and the executor's hit/miss counters make that
-visible (``BENCH_serve.json`` reports the hit rate).
+An ``Executor`` runs chunks of requests for one ``GGPUConfig`` through
+the engine's one entry point, ``repro.ggpu.engine.launch``, which picks
+the cohort or batch path from the chunk itself, and tracks the **envelope
+cache**: the set of compiled-stepper signatures this process has already
+traced. Each dispatch's envelope is the signature the engine reports it
+jitted (``LaunchHandle.envelope``), suffixed with the executor's
+placement, so a chunk whose envelope has been seen re-uses the compiled
+stepper — repeat serving traffic never re-traces — and the executor's
+hit/miss counters make that visible (``BENCH_serve.json`` reports the hit
+rate).
 
 Every executor separates its **simulation config** (``sim_cfg``:
 ``freq_mhz`` normalized out, the engine/compile key — frequency never
@@ -36,11 +38,11 @@ reports at the caller's true frequency. The registry is shared with
 config share both the compiled steppers and the memoized bench results.
 
 An executor also carries its **placement**: a ``mesh`` shards every
-cohort/batch chunk's launch axis across the mesh's data-parallel devices
-(``repro.ggpu.engine`` ``mesh=`` entry points — one dispatch, each
-physical device stepping its own slice), and a ``device`` pins dispatch
-to one ``jax.Device`` (how a fleet puts different simulated configs on
-different physical devices so their compute genuinely overlaps). Both
+multi-launch chunk's launch axis across the mesh's data-parallel devices
+(``launch(mesh=)`` — one dispatch, each physical device stepping its own
+slice), and a ``device`` pins dispatch to one ``jax.Device`` (how a fleet
+puts different simulated configs on different physical devices so their
+compute genuinely overlaps). Both
 default off; with one JAX device everything degrades to the PR-5
 single-device behavior. ``shards`` reports the mesh's data-parallel
 extent (1 = unsharded) — schedulers scale their chunk planning by it.
@@ -56,12 +58,8 @@ from typing import Dict, List, Optional, Sequence
 import jax
 
 from repro import tracing
-from repro.ggpu.engine import (BlockPatch, GGPUConfig, KernelLaunchError,
-                               LaunchHandle, XorBlockPatch,
-                               cohort_rows, launch_shards)
-from repro.ggpu.engine import (run_kernel_async, run_kernel_batch_async,
-                               run_kernel_cohort_async)
-from repro.ggpu.engine.stepper import _n_wavefronts
+from repro.ggpu.engine import (GGPUConfig, KernelLaunchError, LaunchHandle,
+                               Patch, launch, launch_shards)
 
 from repro.serve.request import Request, Result
 
@@ -127,9 +125,7 @@ class PendingChunk:
     executor timeouts and fleet-level hedging. ``seq`` numbers dispatches
     in this process; the executor's program spans carry it as ``chunk``."""
     handle: LaunchHandle
-    kind: str
     reqs: List[Request]
-    env: tuple
     traced: bool
     t_dispatch: float = 0.0
     seq: int = 0
@@ -139,13 +135,13 @@ _DISPATCH_SEQ = itertools.count()
 
 
 class Executor:
-    """Runs (kind, requests) chunks on one config, with envelope-cache
+    """Runs chunks of requests on one config, with envelope-cache
     accounting and a memo dict shared across its users (see module doc).
 
     ``share`` hands this executor another one's mutable state (envelope
     cache, stats, memo) — how the registry builds frequency-faithful views
     over one canonical executor per simulation key. ``mesh`` and ``device``
-    set placement (module doc): a mesh shards cohort/batch launch axes
+    set placement (module doc): a mesh shards multi-launch chunks
     data-parallel, a device pins dispatch. Placement enters the envelope
     key, so differently-placed chunks never alias a compiled signature."""
 
@@ -174,92 +170,40 @@ class Executor:
             self.memo = share.memo
             self._envelopes = share._envelopes
 
-    # -- envelope accounting ------------------------------------------------
-
-    def _envelope(self, kind: str, reqs: Sequence[Request]) -> tuple:
-        """The static signature the engine jit-caches on for this chunk
-        (opcode sets come from the requests' content-keyed cache), suffixed
-        with this executor's placement — a sharded or pinned dispatch is a
-        different compiled artifact than the plain one."""
-        cfg = self.sim_cfg
-        place = (self.shards, None if self.device is None else self.device.id)
-        if kind == "cohort":
-            # the engine buckets cohort sizes (cohort_rows), so the traced
-            # envelope is the bucket, not the raw member count
-            r = reqs[0]
-            return ("cohort", cohort_rows(len(reqs), self.shards),
-                    _n_wavefronts(r.n_items, cfg),
-                    r.prog.shape[0], r.mem0.shape[0], r.static_ops(), place)
-        if kind == "batch":
-            P = max(r.prog.shape[0] for r in reqs)
-            M = max(r.mem0.shape[0] for r in reqs)
-            W = max(_n_wavefronts(r.n_items, cfg) for r in reqs)
-            ops = tuple(sorted(set().union(
-                *(r.static_ops() for r in reqs))))
-            return ("batch", len(reqs), W, P, M, ops, place)
-        r = reqs[0]
-        return ("single", _n_wavefronts(r.n_items, cfg), r.prog.shape[0],
-                r.mem0.shape[0], r.static_ops(), place)
-
     # -- execution ----------------------------------------------------------
 
-    def submit(self, kind: str, reqs: Sequence[Request],
-               patches=None) -> PendingChunk:
+    def submit(self, reqs: Sequence[Request],
+               patches: Sequence[Patch] = ()) -> PendingChunk:
         """Stage and dispatch one planned chunk asynchronously; returns
         while the device still runs. Pair with ``collect``. ``patches``
-        optionally overwrites regions of the chunk's staged memory with
-        device arrays before dispatch — a ``repro.ggpu.engine.BlockPatch``
-        or one ``[(lo, hi, src), ...]`` list per launch — the
+        (``repro.ggpu.engine.Patch``, applied in order) write device
+        arrays into the chunk's staged memory before dispatch — the
         device-resident chaining path a dependency-aware scheduler uses to
         feed a producer's output into a consumer with no host transfer.
         The work runs in program span ``executor.stage``."""
         reqs = list(reqs)
         seq = next(_DISPATCH_SEQ)
         with tracing.span("executor.stage", chunk=seq, launches=len(reqs)):
-            return self._submit(kind, reqs, patches, seq)
+            return self._submit(reqs, patches, seq)
 
-    def _submit(self, kind, reqs, patches, seq) -> PendingChunk:
-        if len(reqs) == 1:
-            kind = "single"          # a degenerate chunk needs no folding
-        env = self._envelope(kind, reqs)
+    def _submit(self, reqs, patches, seq) -> PendingChunk:
+        place = (jax.default_device(self.device) if self.device is not None
+                 else contextlib.nullcontext())
+        with place:
+            h = launch([r.prog for r in reqs], [r.mem0 for r in reqs],
+                       [r.n_items for r in reqs], self.sim_cfg,
+                       out_regions=[r.out_region for r in reqs],
+                       patches=patches, mesh=self.mesh)
+        # a sharded or pinned dispatch is a different compiled artifact
+        # than the plain one: placement suffixes the engine's envelope
+        env = h.envelope + (self.shards,
+                            None if self.device is None else self.device.id)
         traced = env in self._envelopes
         # the jit trace is paid HERE, at dispatch — record the envelope
         # now so identical-envelope chunks dispatched ahead in the same
         # pipeline window count as the hits they really are
         self._envelopes.add(env)
-        regions = [r.out_region for r in reqs]
-        if all(r is None for r in regions):
-            regions = None
-        cfg = self.sim_cfg
-        place = (jax.default_device(self.device) if self.device is not None
-                 else contextlib.nullcontext())
-        with place:
-            if kind == "cohort":
-                h = run_kernel_cohort_async(
-                    reqs[0].prog, [r.mem0 for r in reqs], reqs[0].n_items,
-                    cfg, out_regions=regions, patches=patches,
-                    mesh=self.mesh)
-            elif kind == "batch":
-                h = run_kernel_batch_async(
-                    [r.prog for r in reqs], [r.mem0 for r in reqs],
-                    [r.n_items for r in reqs], cfg, out_regions=regions,
-                    patches=patches, mesh=self.mesh)
-            else:
-                # normalize the chunk-level patch forms down to the
-                # single-launch flat list the engine entry point takes
-                single = None
-                if isinstance(patches, XorBlockPatch):
-                    single = [(patches.lo, patches.hi, patches.block[0],
-                               "xor")]
-                elif isinstance(patches, BlockPatch):
-                    single = [(patches.lo, patches.hi, patches.block[0])]
-                elif patches is not None:
-                    single = patches[0]
-                h = run_kernel_async(
-                    reqs[0].prog, reqs[0].mem0, reqs[0].n_items, cfg,
-                    out_region=regions[0] if regions else None,
-                    patches=single)
-        return PendingChunk(h, kind, reqs, env, traced,
+        return PendingChunk(h, reqs, traced,
                             t_dispatch=time.monotonic(), seq=seq)
 
     def chunk_ready(self, pending: PendingChunk) -> bool:
@@ -301,14 +245,13 @@ class Executor:
         self.stats.dispatches += 1
         results = []
         for mem, info in outs:
-            info.setdefault("batch_size", 1)
             info["time_us"] = info["cycles"] / self.cfg.freq_mhz
             results.append(Result(mem, info))
         return results
 
-    def run(self, kind: str, reqs: Sequence[Request]) -> List[Result]:
+    def run(self, reqs: Sequence[Request]) -> List[Result]:
         """Execute one planned chunk synchronously (dispatch + collect)."""
-        return self.collect(self.submit(kind, reqs))
+        return self.collect(self.submit(reqs))
 
 
 # -- process-wide registry (shared with repro.dse.Evaluator) ----------------

@@ -15,7 +15,7 @@ and default lowerings separately.
 Submitting N instances of the same program **stage-major** (all instances'
 stage 0, then all stage 1, …) is the throughput idiom: each stage's
 requests share a kernel key and fold into one cohort dispatch, and the
-consumer chunk's patches collapse into a single fused ``BlockPatch`` read
+consumer chunk's patches collapse into a single all-launch ``Patch`` read
 of the producer chunk — one device op per edge per *chunk*, not per
 request. ``submit_programs`` does exactly that.
 

@@ -63,7 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import tracing
-from repro.ggpu.engine import BlockPatch, GGPUConfig, KernelLaunchError
+from repro.ggpu.engine import GGPUConfig, KernelLaunchError, Patch
 from repro.registry import SCHEDULERS
 from repro.serve.executors import Executor, PendingChunk
 from repro.serve.request import Dep, Request, Result, result_checksum
@@ -111,8 +111,11 @@ class RetryPolicy:
 
 @dataclasses.dataclass(frozen=True)
 class Chunk:
-    """One planned dispatch: ``kind`` in {cohort, batch, single}, and the
-    member positions into the planner's input sequence."""
+    """One planned dispatch: the member positions into the planner's
+    input sequence, and a ``kind`` label. ``"drop"`` tells the scheduler
+    to quarantine the members instead of dispatching them; the
+    cohort/batch/single labels only describe the plan — the engine picks
+    its path from the launches themselves."""
     kind: str
     members: Tuple[int, ...]
 
@@ -406,8 +409,7 @@ class Scheduler:
                         continue
                     taken += len(reqs)
                     pending = self.executor.submit(
-                        chunk.kind, reqs,
-                        self._chunk_patches(reqs))
+                        reqs, self._chunk_patches(reqs))
                     self._inflight.append(pending)
                     self._inflight_tickets.update(r.ticket for r in reqs)
                     self._note_dispatched(pending)
@@ -427,28 +429,27 @@ class Scheduler:
             if self._dep_waiters.get(r.ticket):
                 self._resident[r.ticket] = (pending, idx)
 
-    def _chunk_patches(self, reqs: Sequence[Request]):
-        """Build the device-resident patches for one planned chunk: the
-        fused ``BlockPatch`` when every member draws the same region from
-        producers co-located in one resident chunk (one device op feeds
-        the whole chunk), per-launch patch lists otherwise, ``None`` when
-        the chunk has no dependencies."""
+    def _chunk_patches(self, reqs: Sequence[Request]) -> List[Patch]:
+        """Build the device-resident patches for one planned chunk: one
+        ``Patch`` for every member when every member draws the same region
+        from producers co-located in one resident chunk (one device op
+        feeds the whole chunk), one per (member, dependency) otherwise,
+        none when the chunk has no dependencies."""
         if not any(r.deps for r in reqs):
-            return None
+            return []
         fused = self._fused_patch(reqs)
         if fused is not None:
-            return fused
-        per = []
-        for r in reqs:
-            plist = []
+            return [fused]
+        patches = []
+        for i, r in enumerate(reqs):
             for d in r.deps:
                 chunk, idx = self._resident[d.producer]
-                plist.append((d.dst[0], d.dst[1],
-                              chunk.handle.device_mem(idx, d.src)))
-            per.append(plist or None)
-        return per
+                src = chunk.handle.device_mem(idx, d.src)
+                patches.append(Patch(d.dst[0], d.dst[1], src[None],
+                                     rows=(i,)))
+        return patches
 
-    def _fused_patch(self, reqs: Sequence[Request]):
+    def _fused_patch(self, reqs: Sequence[Request]) -> Optional[Patch]:
         """The chunk-to-chunk fast path: every member has exactly one dep,
         all with identical (dst, src) regions, and every producer lives in
         the same resident chunk — one fused slice of the producer chunk's
@@ -468,7 +469,7 @@ class Scheduler:
         if idxs != list(range(len(chunk0.reqs))):
             block = jnp.take(block, jnp.asarray(np.asarray(idxs, np.int32)),
                              axis=0)
-        return BlockPatch(d0.dst[0], d0.dst[1], block)
+        return Patch(d0.dst[0], d0.dst[1], block)
 
     def collect(self) -> List[Result]:
         """Resolve every in-flight chunk (dispatch order) and return all
@@ -687,7 +688,7 @@ class Scheduler:
                 if not survivors:
                     return out
                 pending = self.executor.submit(
-                    pending.kind, survivors, self._chunk_patches(survivors))
+                    survivors, self._chunk_patches(survivors))
                 self._note_dispatched(pending)
                 continue
             redo: List[Request] = []
@@ -717,9 +718,7 @@ class Scheduler:
             if not redo:
                 return out
             self._backoff(max(r.attempts for r in redo))
-            pending = self.executor.submit(
-                pending.kind if len(redo) > 1 else "single", redo,
-                self._chunk_patches(redo))
+            pending = self.executor.submit(redo, self._chunk_patches(redo))
             self._note_dispatched(pending)
 
 
@@ -800,9 +799,9 @@ class LaunchQueue:
                 + f" hit max_steps without halting; discard({ticket}) "
                 f"and flush() again to retry the rest", ticket) from exc
 
-        for kind, chunk in self._plan_chunks(pending):
+        for _, chunk in self._plan_chunks(pending):
             try:
-                outs = self.executor.run(kind, [pending[i] for i in chunk])
+                outs = self.executor.run([pending[i] for i in chunk])
             except KernelLaunchError as exc:
                 blame(chunk, exc)
             for i, out in zip(chunk, outs):

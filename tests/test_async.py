@@ -1,5 +1,6 @@
 """Async launch pipeline: LaunchHandle futures are bit-exact vs the sync
-entry points on all 8 benches across {single, cohort, batch} and through
+entry point on all 8 benches across one launch, a cohort and a batch, and
+through
 interleaved pipelined drains; donation never invalidates caller data and
 is never read back after dispatch; failures surface through the handle;
 the executor registry is frequency-faithful; opcode sets are
@@ -8,10 +9,7 @@ import numpy as np
 import pytest
 
 from repro.ggpu import programs
-from repro.ggpu.engine import (GGPUConfig, KernelLaunchError, run_kernel,
-                               run_kernel_async, run_kernel_batch,
-                               run_kernel_batch_async, run_kernel_cohort,
-                               run_kernel_cohort_async)
+from repro.ggpu.engine import GGPUConfig, KernelLaunchError, launch, run_kernel
 from repro.ggpu.engine.stepper import _static_ops
 from repro.ggpu.isa import Assembler
 from repro.serve import Request, Scheduler, get_executor, sim_key
@@ -50,9 +48,10 @@ def _check(result, direct):
 
 @pytest.mark.parametrize("name", sorted(SMALL))
 def test_async_bitexact_all_paths_and_interleaved_drain(name):
-    """Handles from all three async entry points, and a pipelined
-    scheduler drain interleaved under a budget, return the same bits
-    (mem, cycles, stats) as direct sync ``run_kernel`` on every bench."""
+    """Handles from ``launch`` on one launch, a cohort and a batch, and a
+    pipelined scheduler drain interleaved under a budget, return the same
+    bits (mem, cycles, stats) as direct sync ``run_kernel`` on every
+    bench."""
     b = SMALL[name]()
     progA = b.gpu_prog
     progB = _pad_prog(progA, 1)
@@ -61,14 +60,12 @@ def test_async_bitexact_all_paths_and_interleaved_drain(name):
     launches = [(progB, m1), (progA, m0), (progA, m2), (progC, m0)]
     direct = [run_kernel(p, m, b.gpu_items, CFG) for p, m in launches]
 
-    # engine-level async handles: single / cohort / batch
-    _check(run_kernel_async(progA, m0, b.gpu_items, CFG).result(),
-           direct[1])
-    hc = run_kernel_cohort_async(progA, [m0, m2], b.gpu_items, CFG)
+    # engine-level async handles: one launch / cohort / batch
+    _check(launch([progA], [m0], [b.gpu_items], CFG).result(), direct[1])
+    hc = launch([progA, progA], [m0, m2], [b.gpu_items] * 2, CFG)
     for out, d in zip(hc.results(), (direct[1], direct[2])):
         _check(out, d)
-    hb = run_kernel_batch_async([progB, progC], [m1, m0],
-                                [b.gpu_items, b.gpu_items], CFG)
+    hb = launch([progB, progC], [m1, m0], [b.gpu_items] * 2, CFG)
     for out, d in zip(hb.results(), (direct[0], direct[3])):
         _check(out, d)
 
@@ -94,23 +91,22 @@ def test_out_region_sliced_download():
     lo, hi = b.gpu_out.start, b.gpu_out.stop
     full, dinfo = run_kernel(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG)
 
-    h = run_kernel_async(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG,
-                         out_region=(lo, hi))
+    h = launch([b.gpu_prog], [b.gpu_mem], [b.gpu_items], CFG,
+               out_regions=[(lo, hi)])
     mem, info = h.result()
     np.testing.assert_array_equal(mem, full[lo:hi])
     assert info["cycles"] == dinfo["cycles"]
 
     m2 = _variant_mem(b, 5)
     full2, _ = run_kernel(b.gpu_prog, m2, b.gpu_items, CFG)
-    hc = run_kernel_cohort_async(b.gpu_prog, [b.gpu_mem, m2], b.gpu_items,
-                                 CFG, out_regions=[(lo, hi), None])
+    hc = launch([b.gpu_prog] * 2, [b.gpu_mem, m2], [b.gpu_items] * 2, CFG,
+                out_regions=[(lo, hi), None])
     outs = hc.results()
     np.testing.assert_array_equal(outs[0][0], full[lo:hi])
     np.testing.assert_array_equal(outs[1][0], full2)   # None: full image
 
-    hb = run_kernel_batch_async(
-        [b.gpu_prog, _pad_prog(b.gpu_prog, 1)], [b.gpu_mem, m2],
-        [b.gpu_items] * 2, CFG, out_regions=[(lo, hi), (0, 0)])
+    hb = launch([b.gpu_prog, _pad_prog(b.gpu_prog, 1)], [b.gpu_mem, m2],
+                [b.gpu_items] * 2, CFG, out_regions=[(lo, hi), (0, 0)])
     outs = hb.results()
     np.testing.assert_array_equal(outs[0][0], full[lo:hi])
     assert outs[1][0].shape == (0,)                    # cycles-only
@@ -124,8 +120,8 @@ def test_out_region_sliced_download():
     assert r1.mem.shape == (0,) and r1.info["cycles"] == dinfo["cycles"]
 
     with pytest.raises(ValueError):
-        run_kernel_async(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG,
-                         out_region=(0, b.gpu_mem.shape[0] + 1))
+        launch([b.gpu_prog], [b.gpu_mem], [b.gpu_items], CFG,
+               out_regions=[(0, b.gpu_mem.shape[0] + 1)])
 
 
 def test_donation_safety():
@@ -135,23 +131,21 @@ def test_donation_safety():
     sync re-run from the same host array is unaffected."""
     b = SMALL["copy"]()
     before = b.gpu_mem.copy()
-    h = run_kernel_async(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG)
+    h = launch([b.gpu_prog], [b.gpu_mem], [b.gpu_items], CFG)
     assert h.donated.is_deleted()            # donated, not merely unused
     np.testing.assert_array_equal(b.gpu_mem, before)   # caller untouched
     mem, _ = h.result()
     np.testing.assert_array_equal(mem[b.gpu_out], b.ref(b.gpu_mem, b.gpu_n))
 
-    hc = run_kernel_cohort_async(b.gpu_prog, [b.gpu_mem, b.gpu_mem],
-                                 b.gpu_items, CFG)
+    hc = launch([b.gpu_prog] * 2, [b.gpu_mem, b.gpu_mem], [b.gpu_items] * 2,
+                CFG)
     assert hc.donated.is_deleted()
-    hb = run_kernel_batch_async([b.gpu_prog, _pad_prog(b.gpu_prog, 1)],
-                                [b.gpu_mem, b.gpu_mem],
-                                [b.gpu_items] * 2, CFG)
+    hb = launch([b.gpu_prog, _pad_prog(b.gpu_prog, 1)],
+                [b.gpu_mem, b.gpu_mem], [b.gpu_items] * 2, CFG)
     assert hb.donated.is_deleted()
     np.testing.assert_array_equal(b.gpu_mem, before)
     # the same host image dispatches again cleanly (fresh staging copy)
-    _check(run_kernel_async(b.gpu_prog, b.gpu_mem, b.gpu_items,
-                            CFG).result(),
+    _check(launch([b.gpu_prog], [b.gpu_mem], [b.gpu_items], CFG).result(),
            run_kernel(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG))
 
 
@@ -167,21 +161,19 @@ def test_launch_handle_surfaces_failure():
     path — and the error repeats on re-resolution."""
     cfg = GGPUConfig(max_steps=50)
     b = programs._copy(8, 64)
-    h = run_kernel_async(_spinner(), np.zeros(8, np.int32), 8, cfg)
+    h = launch([_spinner()], [np.zeros(8, np.int32)], [8], cfg)
     with pytest.raises(KernelLaunchError) as exc:
         h.result()
     assert exc.value.index == 0
     with pytest.raises(KernelLaunchError):   # sticky: wait() re-raises
         h.wait()
 
-    hc = run_kernel_cohort_async(_spinner(), [np.zeros(8, np.int32)] * 2,
-                                 8, cfg)
+    hc = launch([_spinner()] * 2, [np.zeros(8, np.int32)] * 2, [8, 8], cfg)
     with pytest.raises(KernelLaunchError):
         hc.results()
 
-    hb = run_kernel_batch_async(
-        [b.gpu_prog, _spinner()], [b.gpu_mem, np.zeros(8, np.int32)],
-        [b.gpu_items, 8], cfg)
+    hb = launch([b.gpu_prog, _spinner()], [b.gpu_mem, np.zeros(8, np.int32)],
+                [b.gpu_items, 8], cfg)
     with pytest.raises(KernelLaunchError) as exc:
         hb.results()
     assert exc.value.index == 1
@@ -226,12 +218,11 @@ def test_registry_is_frequency_faithful():
     assert get_executor(cfg667) is ex        # views are cached too
 
     b = SMALL["copy"]()
-    (res,) = ex.run("single", [Request(b.gpu_prog, b.gpu_mem, b.gpu_items)])
+    (res,) = ex.run([Request(b.gpu_prog, b.gpu_mem, b.gpu_items)])
     assert res.info["time_us"] == pytest.approx(
         res.info["cycles"] / 667.0)
     # same envelope through the canonical executor: shared trace cache hits
-    (res500,) = canon.run("single",
-                          [Request(b.gpu_prog, b.gpu_mem, b.gpu_items)])
+    (res500,) = canon.run([Request(b.gpu_prog, b.gpu_mem, b.gpu_items)])
     assert res500.info["cycles"] == res.info["cycles"]
     assert res500.info["time_us"] == pytest.approx(
         res.info["cycles"] / 500.0)
@@ -270,21 +261,22 @@ def test_trace_hits_counted_across_pipeline_window():
 
 
 def test_sync_entries_accept_iterators():
-    """run_kernel_cohort/batch materialize sequence inputs exactly once —
-    a generator argument is not consumed by the emptiness guard."""
+    """``launch`` materializes sequence inputs exactly once — generator
+    arguments are not consumed by its checks — on the cohort and the
+    batch path, and an empty call raises rather than dispatching."""
     b = SMALL["copy"]()
     direct = run_kernel(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG)
-    outs = run_kernel_cohort(b.gpu_prog,
-                             (m for m in [b.gpu_mem, _variant_mem(b, 1)]),
-                             b.gpu_items, CFG)
+    outs = launch((p for p in [b.gpu_prog] * 2),
+                  (m for m in [b.gpu_mem, _variant_mem(b, 1)]),
+                  (n for n in [b.gpu_items] * 2), CFG).results()
     assert len(outs) == 2
     _check(outs[0], direct)
-    assert run_kernel_cohort(b.gpu_prog, iter([]), b.gpu_items, CFG) == []
-    outs = run_kernel_batch((p for p in [b.gpu_prog]),
-                            (m for m in [b.gpu_mem]),
-                            (n for n in [b.gpu_items]), CFG)
+    outs = launch((p for p in [b.gpu_prog, _pad_prog(b.gpu_prog, 1)]),
+                  (m for m in [b.gpu_mem, b.gpu_mem]),
+                  (n for n in [b.gpu_items] * 2), CFG).results()
     _check(outs[0], direct)
-    assert run_kernel_batch(iter([]), iter([]), iter([]), CFG) == []
+    with pytest.raises(ValueError):
+        launch(iter([]), iter([]), iter([]), CFG)
 
 
 def test_request_static_ops_content_cached():

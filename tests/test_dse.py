@@ -86,14 +86,15 @@ def test_depth_increases_cpi_not_results():
 def test_depth_batching_invariants():
     """Cohort/batch launches charge the pipeline feedback identically to a
     single launch."""
-    from repro.ggpu.engine import run_kernel_batch, run_kernel_cohort
+    from repro.ggpu.engine import launch
     b = _bench("xcorr")
     cfg = GGPUConfig(n_cus=2, pipeline_depth=2)
     mem_s, i_s = run_kernel(b.gpu_prog, b.gpu_mem, b.gpu_items, cfg)
-    (mem_c, i_c), = run_kernel_cohort(b.gpu_prog, [b.gpu_mem],
-                                      b.gpu_items, cfg)
-    (mem_b, i_b), = run_kernel_batch([b.gpu_prog], [b.gpu_mem],
-                                     [b.gpu_items], cfg)
+    padded = np.vstack([b.gpu_prog, np.zeros_like(b.gpu_prog[:1])])
+    (mem_c, i_c), _ = launch([b.gpu_prog] * 2, [b.gpu_mem] * 2,
+                             [b.gpu_items] * 2, cfg).results()
+    (mem_b, i_b), _ = launch([b.gpu_prog, padded], [b.gpu_mem] * 2,
+                             [b.gpu_items] * 2, cfg).results()
     np.testing.assert_array_equal(mem_s, mem_c)
     np.testing.assert_array_equal(mem_s, mem_b)
     assert i_s["cycles"] == i_c["cycles"] == i_b["cycles"]

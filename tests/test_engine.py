@@ -1,13 +1,13 @@
-"""Execution-engine invariants: fused dispatch and batched launches are
-bit-exact vs the legacy single-launch path; the pluggable memory systems
-preserve functional results; the LaunchQueue groups and orders correctly."""
+"""Execution-engine invariants: fused dispatch and multi-launch calls
+(cohort and batch) are bit-exact vs the single-launch path; ``launch``
+picks its path from its input; the pluggable memory systems preserve
+functional results; the LaunchQueue groups and orders correctly."""
 import numpy as np
 import pytest
 
 from repro.ggpu import programs
 from repro.ggpu.engine import (MEMSYS_REGISTRY, GGPUConfig, ScalarConfig,
-                               get_memsys, run_kernel, run_kernel_batch,
-                               run_kernel_cohort)
+                               get_memsys, launch, run_kernel)
 from repro.ggpu.isa import Assembler
 from repro.serve.engine import LaunchQueue
 
@@ -36,27 +36,6 @@ def test_fused_dispatch_bit_exact():
         assert i1[k] == i8[k], k
 
 
-def test_batch_matches_single_mixed_shapes():
-    """A batch of different programs/memory sizes/item counts reproduces
-    each single launch bit-exactly (results AND cycle counts)."""
-    cfg = GGPUConfig(n_cus=2)
-    c = programs._copy(64, 1024)
-    n = 128
-    launches = [
-        (c.gpu_prog, c.gpu_mem, c.gpu_items),
-        (_divergent_prog(n), np.zeros(2 * n, np.int32), n),
-    ]
-    singles = [run_kernel(p, m, k, cfg) for p, m, k in launches]
-    batch = run_kernel_batch([p for p, _, _ in launches],
-                             [m for _, m, _ in launches],
-                             [k for _, _, k in launches], cfg)
-    for (ms, is_), (mb, ib) in zip(singles, batch):
-        np.testing.assert_array_equal(ms, mb)
-        for key in ("cycles", "instrs", "mem_ops", "hits", "misses",
-                    "steps"):
-            assert is_[key] == ib[key], key
-
-
 def test_legacy_reference_bit_exact():
     """The seed-faithful legacy stepper and the optimized engine agree on
     everything observable."""
@@ -70,30 +49,70 @@ def test_legacy_reference_bit_exact():
         assert i_n[k] == i_l[k], k
 
 
-def test_cohort_matches_single():
-    """A same-kernel cohort (folded into the wavefront axis) reproduces
-    each single launch bit-exactly, including cycles."""
-    b = programs._xcorr(32, 256)
+def _launch_inputs(path):
+    """Three xcorr launches over different images (one kernel: a cohort),
+    or a copy and a divergent kernel of different programs, memory sizes
+    and item counts (a batch)."""
+    if path == "cohort":
+        b = programs._xcorr(32, 256)
+        rng = np.random.default_rng(11)
+        mems = [np.concatenate([rng.integers(-20, 20, 512).astype(np.int32),
+                                np.zeros(256, np.int32)]) for _ in range(3)]
+        return [(b.gpu_prog, m, b.gpu_items) for m in mems]
+    c = programs._copy(64, 1024)
+    n = 128
+    return [(c.gpu_prog, c.gpu_mem, c.gpu_items),
+            (_divergent_prog(n), np.zeros(2 * n, np.int32), n)]
+
+
+@pytest.mark.parametrize("path", ["cohort", "batch"])
+def test_launch_matches_single(path):
+    """A same-kernel cohort (folded into the wavefront axis) and a batch
+    of different programs/memory sizes/item counts (vmapped) reproduce
+    each single launch bit-exactly, results AND cycle counts."""
     cfg = GGPUConfig(n_cus=2)
-    rng = np.random.default_rng(11)
-    mems = [np.concatenate([rng.integers(-20, 20, 512).astype(np.int32),
-                            np.zeros(256, np.int32)]) for _ in range(3)]
-    singles = [run_kernel(b.gpu_prog, m, b.gpu_items, cfg) for m in mems]
-    cohort = run_kernel_cohort(b.gpu_prog, mems, b.gpu_items, cfg)
-    for (ms, is_), (mc, ic) in zip(singles, cohort):
-        np.testing.assert_array_equal(ms, mc)
+    launches = _launch_inputs(path)
+    singles = [run_kernel(p, m, k, cfg) for p, m, k in launches]
+    h = launch(*zip(*launches), cfg)
+    assert h.envelope[0] == path
+    for (ms, is_), (ml, il) in zip(singles, h.results()):
+        np.testing.assert_array_equal(ms, ml)
         for key in ("cycles", "instrs", "mem_ops", "hits", "misses",
                     "steps"):
-            assert is_[key] == ic[key], key
-        assert ic["batch_size"] == 3
+            assert is_[key] == il[key], key
+        assert il["batch_size"] == len(launches)
 
 
-def test_cohort_rejects_mixed_mem_shapes():
+def _pad(prog, rows=1):
+    return np.vstack([prog, np.zeros((rows, prog.shape[1]), np.int32)])
+
+
+@pytest.mark.parametrize("differ", ["nothing", "mem_size", "program",
+                                    "n_items"])
+def test_launch_picks_path_from_input(differ):
+    """``launch`` stages a cohort exactly when every launch shares program
+    bytes, item count and memory size, a batch otherwise — and reports the
+    signature it jitted (cohort: bucketed rows, memory size; batch: the
+    padded memory envelope) as ``envelope``."""
     b = programs._copy(64, 256)
-    with pytest.raises(ValueError):
-        run_kernel_cohort(b.gpu_prog,
-                          [b.gpu_mem, np.zeros(7, np.int32)],
-                          b.gpu_items, GGPUConfig())
+    cfg = GGPUConfig()
+    progs, mems, ns = [b.gpu_prog] * 3, [b.gpu_mem] * 3, [b.gpu_items] * 3
+    if differ == "mem_size":
+        mems = mems[:2] + [np.zeros(b.gpu_mem.shape[0] + 5, np.int32)]
+    elif differ == "program":
+        progs = progs[:2] + [_pad(b.gpu_prog)]
+    elif differ == "n_items":
+        ns = ns[:2] + [b.gpu_items - 1]
+    h = launch(progs, mems, ns, cfg)
+    W = -(-b.gpu_items // cfg.wavefront)
+    if differ == "nothing":
+        assert h.envelope[:5] == ("cohort", 4, W, b.gpu_prog.shape[0],
+                                  b.gpu_mem.shape[0])
+    else:
+        assert h.envelope[:5] == ("batch", 3, W,
+                                  max(p.shape[0] for p in progs),
+                                  max(m.shape[0] for m in mems))
+    assert len(h.results()) == 3
 
 
 def test_batch_clips_at_each_launchs_own_memory_size():
@@ -107,23 +126,33 @@ def test_batch_clips_at_each_launchs_own_memory_size():
     mem_small = np.arange(2 * n, dtype=np.int32)          # last word: 127
     big = programs._copy(64, 1024)                        # forces padding
     single = run_kernel(prog, mem_small, n, GGPUConfig())
-    batch = run_kernel_batch([prog, big.gpu_prog],
-                             [mem_small, big.gpu_mem],
-                             [n, big.gpu_items], GGPUConfig())
+    batch = launch([prog, big.gpu_prog], [mem_small, big.gpu_mem],
+                   [n, big.gpu_items], GGPUConfig()).results()
     np.testing.assert_array_equal(single[0], batch[0][0])
     assert batch[0][0][n] == 2 * n - 1                    # clipped in-image
     assert single[1]["cycles"] == batch[0][1]["cycles"]
 
 
-def test_batch_empty_and_single():
-    assert run_kernel_batch([], [], [], GGPUConfig()) == []
+def test_launch_empty_and_single():
+    """One launch is a cohort of one, bit-exact with ``run_kernel``; an
+    empty call, mismatched argument lengths and a multi-launch legacy
+    call are refused."""
     b = programs._copy(64, 256)
-    (mem_b, info_b), = run_kernel_batch([b.gpu_prog], [b.gpu_mem],
-                                        [b.gpu_items], GGPUConfig())
+    h = launch([b.gpu_prog], [b.gpu_mem], [b.gpu_items], GGPUConfig())
+    assert h.envelope[:2] == ("cohort", 1)
+    mem_l, info_l = h.result()
     mem_s, info_s = run_kernel(b.gpu_prog, b.gpu_mem, b.gpu_items,
                                GGPUConfig())
-    np.testing.assert_array_equal(mem_b, mem_s)
-    assert info_b["cycles"] == info_s["cycles"]
+    np.testing.assert_array_equal(mem_l, mem_s)
+    assert info_l["cycles"] == info_s["cycles"]
+    with pytest.raises(ValueError):
+        launch([], [], [], GGPUConfig())
+    with pytest.raises(ValueError):
+        launch([b.gpu_prog] * 2, [b.gpu_mem], [b.gpu_items] * 2,
+               GGPUConfig())
+    with pytest.raises(ValueError):
+        launch([b.gpu_prog] * 2, [b.gpu_mem] * 2, [b.gpu_items] * 2,
+               GGPUConfig(), legacy=True)
 
 
 @pytest.mark.parametrize("memsys", sorted(MEMSYS_REGISTRY))
@@ -223,18 +252,13 @@ def test_launch_queue_drains_in_ticket_order(monkeypatch):
     import repro.serve.executors as sx
     order = []
 
-    def spy(name, fn):
-        def wrapper(*args, **kw):
-            order.append(name)
-            return fn(*args, **kw)
+    def spy(fn):
+        def wrapper(progs, *args, **kw):
+            order.append(len(progs))
+            return fn(progs, *args, **kw)
         return wrapper
 
-    monkeypatch.setattr(sx, "run_kernel_async",
-                        spy("single", sx.run_kernel_async))
-    monkeypatch.setattr(sx, "run_kernel_cohort_async",
-                        spy("cohort", sx.run_kernel_cohort_async))
-    monkeypatch.setattr(sx, "run_kernel_batch_async",
-                        spy("batch", sx.run_kernel_batch_async))
+    monkeypatch.setattr(sx, "launch", spy(sx.launch))
 
     cfg = GGPUConfig(n_cus=2)
     q = LaunchQueue(cfg)
@@ -245,7 +269,7 @@ def test_launch_queue_drains_in_ticket_order(monkeypatch):
     t2 = q.submit(small.gpu_prog, small.gpu_mem, small.gpu_items)
     results = q.flush()
     # ticket 0's singleton chunk must run before ticket 1's cohort
-    assert order == ["single", "cohort"]
+    assert order == [1, 2]
     assert [info["batch_size"] for _, info in results] == [1, 2, 2]
     for t in (t0, t1, t2):
         assert results[t] is not None

@@ -232,8 +232,7 @@ def test_stuck_device_never_ready():
     ex = Executor(CFG, timeout_s=0.05)
     inj = FaultInjector("d", ex, plan)
     b = _copy_bench()
-    pending = inj.submit("single",
-                         [Request(b.gpu_prog, b.gpu_mem, b.gpu_items)])
+    pending = inj.submit([Request(b.gpu_prog, b.gpu_mem, b.gpu_items)])
     assert not inj.chunk_ready(pending)
     with pytest.raises(DeviceTimeout):
         inj.collect(pending)

@@ -15,7 +15,7 @@ import pytest
 
 from repro.ggpu import programs
 from repro.ggpu.isa import Assembler
-from repro.ggpu.machine import GGPUConfig, ScalarConfig, run_kernel
+from repro.ggpu.engine import GGPUConfig, ScalarConfig, run_kernel
 from repro.ggpu.programs import all_benches
 
 FAST = os.environ.get("GGPU_FAST_TESTS", "0") not in ("", "0")

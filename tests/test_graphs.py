@@ -4,13 +4,19 @@ dependency-aware scheduler, engine-level staged-buffer patches,
 program surface, fleet co-location with learned (kernel, schedule)
 service times, cascade quarantine, and the open-loop load generator
 replayed against a ``Fleet``."""
+import json
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 from repro.compiler import compile_graph
 from repro.ggpu import programs
-from repro.ggpu.engine import (BlockPatch, GGPUConfig, run_kernel,
-                               run_kernel_async, run_kernel_cohort_async)
+from repro.ggpu.engine import GGPUConfig, Patch, launch, run_kernel
 from repro.ggpu.isa import Assembler
 from repro.serve import (Dep, DependencyError, Fleet, Request, Scheduler,
                          bursty_arrivals, extract_outputs, replay,
@@ -54,16 +60,17 @@ def test_single_launch_patch_matches_host_patch():
     patched = b.gpu_mem.copy()
     patched[lo:hi] = src
     direct = run_kernel(b.gpu_prog, patched, b.gpu_items, CFG)
-    h = run_kernel_async(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG,
-                         patches=[(lo, hi, jnp.asarray(src))])
+    h = launch([b.gpu_prog], [b.gpu_mem], [b.gpu_items], CFG,
+               patches=[Patch(lo, hi, jnp.asarray(src)[None])])
     mem, info = h.result()
     np.testing.assert_array_equal(mem, direct[0])
     assert info["cycles"] == direct[1]["cycles"]
 
 
 def test_cohort_patch_block_and_per_launch_match():
-    """Cohort dispatch with a fused ``BlockPatch`` and with per-launch
-    patch lists both reproduce host-side patching, member by member."""
+    """A cohort patched by one all-launch ``Patch`` and by one ``Patch``
+    per launch (``rows=(i,)``) both reproduce host-side patching, member
+    by member."""
     import jax.numpy as jnp
     b = programs._copy(16, 128)
     B, lo, hi = 3, 8, 24
@@ -76,26 +83,136 @@ def test_cohort_patch_block_and_per_launch_match():
         p = m.copy()
         p[lo:hi] = row
         direct.append(run_kernel(b.gpu_prog, p, b.gpu_items, CFG))
-    fused = run_kernel_cohort_async(
-        b.gpu_prog, mems, b.gpu_items, CFG,
-        patches=BlockPatch(lo, hi, jnp.asarray(block))).results()
-    per = run_kernel_cohort_async(
-        b.gpu_prog, mems, b.gpu_items, CFG,
-        patches=[[(lo, hi, jnp.asarray(row))] for row in block]).results()
-    for (dm, di), (fm, fi), (pm, pi) in zip(direct, fused, per):
+    args = ([b.gpu_prog] * B, mems, [b.gpu_items] * B, CFG)
+    fused = launch(*args, patches=[Patch(lo, hi, jnp.asarray(block))])
+    per = launch(*args, patches=[Patch(lo, hi, jnp.asarray(row)[None],
+                                       rows=(i,))
+                                 for i, row in enumerate(block)])
+    for (dm, di), (fm, fi), (pm, pi) in zip(direct, fused.results(),
+                                            per.results()):
         np.testing.assert_array_equal(fm, dm)
         np.testing.assert_array_equal(pm, dm)
         assert fi["cycles"] == di["cycles"] == pi["cycles"]
 
 
-def test_patch_validation():
+@pytest.mark.parametrize("bad", [
+    Patch(5, 2, np.zeros((1, 0), np.int32)),              # hi < lo
+    Patch(0, 4, np.zeros((1, 3), np.int32)),              # block width
+    Patch(0, 4, np.zeros((2, 4), np.int32)),              # block rows
+    Patch(0, 4, np.zeros((1, 4), np.int32), rows=(1,)),   # no launch 1
+    Patch(10**6, 10**6 + 4, np.zeros((1, 4), np.int32)),  # past the image
+    Patch(0, 4, np.zeros((1, 4), np.int32), op="add"),    # unknown op
+])
+def test_patch_validation(bad):
     b = programs._copy(16, 128)
     with pytest.raises(ValueError):
-        run_kernel_async(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG,
-                         patches=[(5, 2, np.zeros(0, np.int32))])
-    with pytest.raises(ValueError):
-        run_kernel_async(b.gpu_prog, b.gpu_mem, b.gpu_items, CFG,
-                         patches=[(0, 4, np.zeros(3, np.int32))])
+        launch([b.gpu_prog], [b.gpu_mem], [b.gpu_items], CFG,
+               patches=[bad])
+
+
+@pytest.mark.parametrize("path", ["cohort", "batch"])
+def test_device_mem_reads_each_launch_on_the_row_view(path):
+    """``device_mem`` and ``device_mem_block`` read each launch's final
+    words out of the row view of either layout — including a padded
+    cohort's real members and an empty region — without a host hop."""
+    b = programs._copy(16, 128)
+    rng = np.random.default_rng(4)
+    mems = [rng.integers(-20, 20, b.gpu_mem.shape[0]).astype(np.int32)
+            for _ in range(3)]                     # a cohort of 3 pads to 4
+    progs = [b.gpu_prog] * 3
+    if path == "batch":
+        progs[1] = np.vstack([b.gpu_prog,
+                              np.zeros_like(b.gpu_prog[:1])])
+    want = [run_kernel(b.gpu_prog, m, b.gpu_items, CFG)[0] for m in mems]
+    h = launch(progs, mems, [b.gpu_items] * 3, CFG)
+    assert h.envelope[0] == path
+    lo, hi = b.gpu_out.start, b.gpu_out.stop
+    for i, w in enumerate(want):
+        np.testing.assert_array_equal(np.asarray(h.device_mem(i)), w)
+        np.testing.assert_array_equal(np.asarray(h.device_mem(i, (lo, hi))),
+                                      w[lo:hi])
+        assert h.device_mem(i, (lo, lo)).shape == (0,)
+    np.testing.assert_array_equal(np.asarray(h.device_mem_block(lo, hi)),
+                                  np.stack([w[lo:hi] for w in want]))
+
+
+# One interpreter with 8 forced host devices runs one Patch list — a set
+# on a row subset, then an xor on every row — on each memory layout and on
+# the host images it describes. The sharded cohort splits 8 launches 1 a
+# device over the 8-way mesh.
+PATCH_LAYOUTS = textwrap.dedent("""
+    import json, os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    import numpy as np
+    from repro.ggpu import programs
+    from repro.ggpu.engine import GGPUConfig, Patch, launch, run_kernel
+    from repro.launch.mesh import make_launch_mesh
+    assert jax.device_count() == 8, jax.device_count()
+    cfg = GGPUConfig(n_cus=2)
+    b = programs._copy(16, 128)
+    rng = np.random.default_rng(3)
+    n = 8
+    mems = [rng.integers(-20, 20, b.gpu_mem.shape[0]).astype(np.int32)
+            for _ in range(n)]
+    pad = np.vstack([b.gpu_prog, np.zeros((1, b.gpu_prog.shape[1]),
+                                          np.int32)])
+    sets = (1, 4, 6)
+    set_block = rng.integers(-99, 99, (len(sets), 16)).astype(np.int32)
+    xor_block = rng.integers(0, 2**20, (n, 8)).astype(np.int32)
+    patches = [Patch(3, 19, jax.numpy.asarray(set_block), rows=sets),
+               Patch(10, 18, xor_block, op="xor")]
+    host = [m.copy() for m in mems]
+    for k, i in enumerate(sets):
+        host[i][3:19] = set_block[k]
+    for i in range(n):
+        host[i][10:18] ^= xor_block[i]
+    out = {}
+    for layout, progs, mesh in [
+            ("flat-cohort", [b.gpu_prog] * n, None),
+            ("sharded-cohort", [b.gpu_prog] * n, make_launch_mesh()),
+            ("batch", [b.gpu_prog, pad] * (n // 2), None)]:
+        h = launch(progs, mems, [b.gpu_items] * n, cfg, patches=patches,
+                   mesh=mesh)
+        want = [run_kernel(p, m, b.gpu_items, cfg)
+                for p, m in zip(progs, host)]
+        got = h.results()
+        out[layout] = {
+            "path": h.envelope[0], "devices": len(h.devices()),
+            "bits": all(np.array_equal(g[0], w[0])
+                        for g, w in zip(got, want)),
+            "cycles": all(g[1]["cycles"] == w[1]["cycles"]
+                          for g, w in zip(got, want))}
+    print("LAYOUTS " + json.dumps(out))
+""")
+
+
+@pytest.fixture(scope="module")
+def patched_layouts():
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    src = str(Path(__file__).resolve().parent.parent / "src")
+    env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+    proc = subprocess.run([sys.executable, "-c", PATCH_LAYOUTS], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr
+    line = [x for x in proc.stdout.splitlines()
+            if x.startswith("LAYOUTS ")][-1]
+    return json.loads(line[len("LAYOUTS "):])
+
+
+@pytest.mark.parametrize("layout, path, devices", [
+    ("flat-cohort", "cohort", 1), ("sharded-cohort", "cohort", 8),
+    ("batch", "batch", 1)])
+def test_patch_list_matches_host_patching(patched_layouts, layout, path,
+                                          devices):
+    """The same ``Patch`` list (a set on a row subset, then an xor on all
+    rows) gives the bits and cycles of patching the host images before
+    launch, on every layout of the staged memory."""
+    got = patched_layouts[layout]
+    assert (got["path"], got["devices"]) == (path, devices)
+    assert got["bits"] and got["cycles"]
 
 
 # -- scheduler: dependency edges -----------------------------------------
