@@ -2,8 +2,12 @@
 
 All functions are pure; params are plain dict subtrees produced by
 ``repro.models.schema``. Params are stored in ``cfg.param_dtype`` (f32) and
-cast to ``cfg.compute_dtype`` (bf16) at the point of use — master weights
-stay full precision for the optimizer.
+cast to ``cfg.compute_dtype`` (bf16) at the point of use. Training keeps
+full-precision master weights for the optimizer, so it casts at every step;
+a serving engine holds the weights as ``schema.served_params`` gives them,
+each leaf the forward reads only in compute dtype already cast to it once at
+load, so the cast here is a no-op. Norm scales and the few leaves read in
+f32 (``ParamSpec.compute_read`` False) keep their stored dtype.
 """
 from __future__ import annotations
 

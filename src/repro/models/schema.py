@@ -6,6 +6,9 @@
   * ``init_params``      : materialized random tree (smoke tests / training)
   * ``param_axes``       : logical-axis tree consumed by the sharding rules
   * ``count_params``     : analytic parameter count for roofline MODEL_FLOPS
+  * ``served_params``    : the tree a serving engine holds, each leaf the
+    forward reads only in compute dtype cast to it once (and its abstract
+    twin ``served_abstract_params``)
 
 Layer stacks are stored *stacked*: each repeated group has params with a
 leading ``repeats`` dim and is executed with ``lax.scan``. Attention
@@ -16,7 +19,7 @@ do not divide a 16-way axis; 5120, 2560, … do).
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass
+from dataclasses import dataclass, replace
 from typing import Dict, Tuple
 
 import jax
@@ -32,15 +35,28 @@ class ParamSpec:
     axes: Tuple[object, ...]          # logical axis name (str) or None per dim
     init: str = "normal"              # normal | zeros | ones | lambda_lru
     scale: float = 1.0
+    # the forward reads this leaf only cast to ``cfg.compute_dtype``, so a
+    # copy cast once gives the same operands (False: read in float32)
+    compute_read: bool = False
 
 
 def _dense(d_in: int, d_out: int, ax_in: str, ax_out: str, *, bias: bool = False,
-           init: str = "normal", scale: float | None = None) -> Dict[str, ParamSpec]:
+           init: str = "normal", scale: float | None = None,
+           compute_read: bool = True) -> Dict[str, ParamSpec]:
     scale = (1.0 / math.sqrt(d_in)) if scale is None else scale
-    out = {"w": ParamSpec((d_in, d_out), (ax_in, ax_out), init, scale)}
+    out = {"w": ParamSpec((d_in, d_out), (ax_in, ax_out), init, scale,
+                          compute_read)}
     if bias:
-        out["b"] = ParamSpec((d_out,), (ax_out,), "zeros")
+        out["b"] = ParamSpec((d_out,), (ax_out,), "zeros",
+                             compute_read=compute_read)
     return out
+
+
+def _conv(cfg: ModelConfig, width: int) -> Dict[str, ParamSpec]:
+    """Depthwise causal conv, read in its input's (compute) dtype."""
+    return {"w": ParamSpec((cfg.conv_width, width), (None, "ffn"), "normal",
+                           0.1, compute_read=True),
+            "b": ParamSpec((width,), ("ffn",), "zeros", compute_read=True)}
 
 
 def _norm(d: int, kind: str) -> Dict[str, ParamSpec]:
@@ -87,9 +103,9 @@ def _moe_schema(cfg: ModelConfig) -> Dict[str, ParamSpec | dict]:
         "norm": _norm(d, cfg.norm),
         "router": {"w": ParamSpec((d, e), ("embed", None), "normal", 1.0 / math.sqrt(d))},
         "wi": ParamSpec((held, d, 2 * ff), ("experts", "embed", "ffn"),
-                        "normal", 1.0 / math.sqrt(d)),
+                        "normal", 1.0 / math.sqrt(d), compute_read=True),
         "wo": ParamSpec((held, ff, d), ("experts", "ffn", "embed"),
-                        "normal", 1.0 / math.sqrt(ff)),
+                        "normal", 1.0 / math.sqrt(ff), compute_read=True),
     }
     if cfg.n_shared_experts:
         sff = cfg.n_shared_experts * ff
@@ -123,13 +139,14 @@ def _rglru_schema(cfg: ModelConfig) -> Dict[str, ParamSpec | dict]:
         "norm": _norm(d, cfg.norm),
         "wx": _dense(d, dr, "embed", "ffn"),                   # recurrent branch in
         "wg": _dense(d, dr, "embed", "ffn"),                   # gate branch
-        "conv": {"w": ParamSpec((cfg.conv_width, dr), (None, "ffn"), "normal", 0.1),
-                 "b": ParamSpec((dr,), ("ffn",), "zeros")},
-        "lru": {
+        "conv": _conv(cfg, dr),
+        "lru": {                                               # read in f32
             "lam": ParamSpec((dr,), ("ffn",), "lambda_lru"),   # Λ, a = σ(Λ)^(c·r)
-            "wa": _dense(dr, dr, "ffn", None, scale=1.0 / math.sqrt(dr)),
+            "wa": _dense(dr, dr, "ffn", None, scale=1.0 / math.sqrt(dr),
+                         compute_read=False),
             "ba": ParamSpec((dr,), (None,), "zeros"),
-            "wi": _dense(dr, dr, "ffn", None, scale=1.0 / math.sqrt(dr)),
+            "wi": _dense(dr, dr, "ffn", None, scale=1.0 / math.sqrt(dr),
+                         compute_read=False),
             "bi": ParamSpec((dr,), (None,), "zeros"),
         },
         "wo": _dense(dr, d, "ffn", "embed"),
@@ -144,8 +161,7 @@ def _mlstm_schema(cfg: ModelConfig) -> Dict[str, ParamSpec | dict]:
     return {
         "norm": _norm(d, cfg.norm),
         "wup": _dense(d, 2 * de, "embed", "ffn"),              # fused x|gate
-        "conv": {"w": ParamSpec((cfg.conv_width, de), (None, "ffn"), "normal", 0.1),
-                 "b": ParamSpec((de,), ("ffn",), "zeros")},
+        "conv": _conv(cfg, de),
         "wq": _dense(de, de, "ffn", None),
         "wk": _dense(de, de, "ffn", None),
         "wv": _dense(de, de, "ffn", None),
@@ -164,7 +180,7 @@ def _slstm_schema(cfg: ModelConfig) -> Dict[str, ParamSpec | dict]:
         "wg": _dense(d, 4 * d, "embed", "ffn"),                # i|f|z|o from x_t
         "rg": ParamSpec((h, hd, 4 * hd), (None, None, None), "normal",
                         1.0 / math.sqrt(hd)),                  # recurrent, per head
-        "bg": ParamSpec((4 * d,), ("ffn",), "zeros"),
+        "bg": ParamSpec((4 * d,), ("ffn",), "zeros", compute_read=True),
         "wo": _dense(d, d, "embed", "qkv"),
     }
 
@@ -214,7 +230,7 @@ def moe_group(cfg: ModelConfig, gi: int) -> bool:
 def _stack(tree, n: int):
     """Prepend a stacked layer dim (axis name None) to every ParamSpec."""
     if isinstance(tree, ParamSpec):
-        return ParamSpec((n, *tree.shape), (None, *tree.axes), tree.init, tree.scale)
+        return replace(tree, shape=(n, *tree.shape), axes=(None, *tree.axes))
     return {k: _stack(v, n) for k, v in tree.items()}
 
 
@@ -225,7 +241,7 @@ def schema(cfg: ModelConfig) -> Dict:
         s["frontend_proj"] = _dense(cfg.d_frontend, d, None, "embed")
     if cfg.frontend != "audio_frames":          # HuBERT: no token embedding
         s["embed"] = {"w": ParamSpec((cfg.vocab_size, d), ("vocab", "embed"),
-                                     "normal", 0.02)}
+                                     "normal", 0.02, compute_read=True)}
     groups = []
     for gi, (unit, reps) in enumerate(layer_groups(cfg)):
         g = {str(i): _block_schema(cfg, kind, moe_group(cfg, gi))
@@ -259,6 +275,43 @@ def tree_map_schema(fn, sch):
 def abstract_params(cfg: ModelConfig):
     dt = jnp.dtype(cfg.param_dtype)
     return tree_map_schema(lambda s: jax.ShapeDtypeStruct(s.shape, dt), schema(cfg))
+
+
+def _served_dtype(cfg: ModelConfig, spec: ParamSpec, dtype):
+    """The dtype a serving engine holds a leaf of ``dtype`` in: the compute
+    dtype where the forward reads the leaf only in it and it is narrower
+    (widening would only add memory), else ``dtype``."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    if spec.compute_read and cd.itemsize < jnp.dtype(dtype).itemsize:
+        return cd
+    return jnp.dtype(dtype)
+
+
+def served_abstract_params(cfg: ModelConfig):
+    """``abstract_params`` as a serving engine holds them."""
+    dt = jnp.dtype(cfg.param_dtype)
+    return tree_map_schema(
+        lambda s: jax.ShapeDtypeStruct(s.shape, _served_dtype(cfg, s, dt)),
+        schema(cfg))
+
+
+def served_casts(cfg: ModelConfig, params):
+    """The leaves of ``params`` that ``served_params`` casts, in tree order."""
+    return [a for s, a in zip(jax.tree.leaves(schema(cfg), is_leaf=_is_spec),
+                              jax.tree.leaves(params))
+            if _served_dtype(cfg, s, a.dtype) != a.dtype]
+
+
+def served_params(cfg: ModelConfig, params):
+    """``params`` with every leaf the forward reads only in a narrower
+    compute dtype cast to it, once. The forward then computes on the same
+    operands (the cast it makes at use becomes a no-op); every other leaf
+    is returned as the same array, so a tree stored in compute dtype is
+    served as it is, with no copy."""
+    def serve(s, a):
+        dt = _served_dtype(cfg, s, a.dtype)
+        return a if dt == a.dtype else a.astype(dt)
+    return jax.tree.map(serve, schema(cfg), params, is_leaf=_is_spec)
 
 
 def param_axes(cfg: ModelConfig):

@@ -8,6 +8,11 @@ This is the serve-side driver the decode dry-run cells lower. Admission
 uses the same FIFO slot-wave planner (``scheduler.plan_waves``) the kernel
 scheduler exposes, and results return in ticket (submission) order — the
 same contract as the kernel path.
+
+The engine holds the weights as it serves them (``schema.served_params``):
+every leaf the forward reads only in the compute dtype is cast to it once,
+when the engine is built, so no decode step converts the weights again.
+The caller's tree (float32 masters, say) stays the caller's.
 """
 from __future__ import annotations
 
@@ -20,6 +25,7 @@ import numpy as np
 
 from repro import tracing
 from repro.models import model as M
+from repro.models import schema as S
 from repro.models.config import ModelConfig
 from repro.models.steps import make_decode_step
 from repro.serve.scheduler import plan_waves
@@ -36,7 +42,19 @@ class EngineConfig:
 
 class Engine:
     def __init__(self, cfg: ModelConfig, params, ecfg: EngineConfig):
-        self.cfg, self.params, self.ecfg = cfg, params, ecfg
+        """``params`` in the schema's layout (or None, to lower only).
+        Span ``engine.load`` (ids ``cast_leaves``, ``cast_mb``: the leaves
+        cast to the compute dtype and the megabytes of the copies) wraps
+        the cast."""
+        self.cfg, self.ecfg = cfg, ecfg
+        if params is not None:
+            cast = S.served_casts(cfg, params)
+            mb = sum(a.size for a in cast) \
+                * jnp.dtype(cfg.compute_dtype).itemsize / 1e6
+            with tracing.span("engine.load", cast_leaves=len(cast),
+                              cast_mb=round(mb, 1)):
+                params = jax.block_until_ready(S.served_params(cfg, params))
+        self.params = params
         # the cache is donated: each step writes its rows into it in place
         self.decode_fn = jax.jit(make_decode_step(cfg), donate_argnums=(1,))
 

@@ -506,3 +506,94 @@ def test_decode_step_donates_the_cache_and_keeps_its_name(stepwise):
     _, new = engine.decode_fn(*args)
     assert all(a.is_deleted() for a in leaves)
     assert jax.tree.structure(new) == jax.tree.structure(cache)
+
+
+# Architectures whose decode the engine serves, each with the leaves read in
+# float32 of its own kind: norms and MLA's kv_norm, the MoE router, RG-LRU's
+# gates and decay, sLSTM's recurrence and mLSTM's output norm.
+SERVED_ARCHS = ["smollm-360m", "deepseek-v2-lite", "recurrentgemma-2b",
+                "xlstm-350m"]
+
+
+def _f32_tree(arch):
+    """A smoke config stored in float32 and a tree for it in which no leaf
+    is exactly a bf16 value (init's ones and zeros are), and the biases,
+    scales and decays are of order one: a leaf cast that the forward reads
+    in float32 then moves the logits."""
+    import jax
+
+    from repro.configs import get_smoke
+    from repro.models.schema import init_params, schema
+    cfg = get_smoke(arch).replace(param_dtype="float32")
+    leaves, tdef = jax.tree.flatten(init_params(cfg, jax.random.PRNGKey(0)))
+    keys = jax.random.split(jax.random.PRNGKey(1), len(leaves))
+    return cfg, jax.tree.unflatten(tdef, [
+        a + (0.01 if s.init == "normal" else 0.5)
+        * jax.random.normal(k, a.shape, a.dtype)
+        for a, k, s in zip(leaves, keys, jax.tree.leaves(schema(cfg)))])
+
+
+@pytest.mark.parametrize("arch", SERVED_ARCHS)
+def test_served_weights_give_the_f32_trees_logits_and_tokens(arch):
+    """The tree the engine serves (each leaf the forward reads only in
+    compute dtype cast once) gives bit for bit the prefill and decode
+    logits of the float32 tree, and the engine the tokens of the
+    step-by-step loop over the float32 tree."""
+    import copy
+
+    import jax
+    import jax.numpy as jnp
+
+    from repro.models import model as M
+    from repro.serve import Engine, EngineConfig
+    cfg, p32 = _f32_tree(arch)
+    engine = Engine(cfg, p32, EngineConfig(slots=2))
+    ref = copy.copy(engine)
+    ref.params = p32
+    assert {str(a.dtype) for a in jax.tree.leaves(engine.params)} \
+        == {"float32", "bfloat16"}
+    # the shapes of a wave of these prompts and 4 new tokens: each decode
+    # step compiles once, here and in the loops below
+    toks = jnp.asarray(np.arange(14).reshape(2, 7) + 3, jnp.int32)
+    out = []
+    for params in (p32, engine.params):
+        logits, cache = M.prefill(params, cfg, tokens=toks, pad_to=12)
+        nxt, _ = engine.decode_fn(params, cache, toks[:, -1:],
+                                  jnp.asarray(7, jnp.int32))
+        out.append([np.asarray(logits, np.float32),
+                    np.asarray(nxt, np.float32)])
+    np.testing.assert_array_equal(out[1][0], out[0][0])
+    np.testing.assert_array_equal(out[1][1], out[0][1])
+    prompts = toks.tolist()
+    assert engine.generate(prompts, 4) == _stepwise_generate(ref, prompts, 4)
+
+
+def test_engine_copies_no_leaf_already_in_its_served_dtype():
+    """A tree stored in the compute dtype is served as it is, every leaf
+    the caller's array; of a float32 tree only the leaves the schema marks
+    as read in float32 keep float32, and the served tree is
+    ``served_abstract_params``."""
+    import jax
+
+    from repro.configs import get_smoke
+    from repro.models import schema as S
+    from repro.serve import Engine, EngineConfig
+    cfg = get_smoke("deepseek-v2-lite").replace(param_dtype="bfloat16")
+    params = S.init_params(cfg, jax.random.PRNGKey(0))
+    engine = Engine(cfg, params, EngineConfig(slots=2))
+    assert S.served_casts(cfg, params) == []
+    assert all(a is b for a, b in zip(jax.tree.leaves(engine.params),
+                                      jax.tree.leaves(params)))
+
+    cfg = get_smoke("smollm-360m").replace(param_dtype="float32")
+    params = S.init_params(cfg, jax.random.PRNGKey(0))
+    engine = Engine(cfg, params, EngineConfig(slots=2))
+    specs = jax.tree.leaves(S.schema(cfg))
+    kept = [a is b for a, b in zip(jax.tree.leaves(engine.params),
+                                   jax.tree.leaves(params))]
+    assert kept == [not s.compute_read for s in specs]
+    assert any(kept) and not all(kept)
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), engine.params) \
+        == jax.tree.map(lambda a: (a.shape, a.dtype),
+                        S.served_abstract_params(cfg))
+    assert len(S.served_casts(cfg, params)) == kept.count(False)

@@ -26,7 +26,7 @@ from repro.ggpu.engine import stepper
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
 from repro.models import model as M
-from repro.models.schema import abstract_params
+from repro.models.schema import schema, served_abstract_params
 from repro.serve import Engine, EngineConfig
 
 COLLECTIVES = ("all-reduce", "all-gather", "all-to-all", "reduce-scatter",
@@ -153,23 +153,38 @@ def _scheduled(text):
     return out
 
 
+@pytest.fixture(scope="module")
+def decode_step(one_chip):
+    """``get(arch) -> (cfg, served abstract params, cache, compiled)``: the
+    engine's decode step compiled for the chip from the tree the engine
+    serves, once per arch for the tests that read it."""
+    done = {}
+
+    def put(tree):
+        return jax.tree.map(lambda a: _shape(a.shape, a.dtype, one_chip),
+                            tree)
+
+    def get(arch):
+        if arch not in done:
+            over, cap = DECODE_CELLS[arch]
+            cfg = get_config(arch).replace(**over)
+            params = served_abstract_params(cfg)
+            cache = put(jax.eval_shape(lambda: M.init_cache(cfg, 16, cap)))
+            engine = Engine(cfg, None, EngineConfig(slots=16))
+            done[arch] = (cfg, params, cache, engine.decode_fn.lower(
+                put(params), cache, _shape((16, 1), jnp.int32, one_chip),
+                _shape((), jnp.int32, one_chip)).compile())
+        return done[arch]
+    return get
+
+
 @pytest.mark.parametrize("arch", sorted(DECODE_CELLS))
-def test_decode_step_updates_the_cache_in_place(one_chip, arch):
+def test_decode_step_updates_the_cache_in_place(decode_step, arch):
     """The engine's decode step, compiled for the chip: the donated cache
     aliases the output, and no copy, slice or fusion the size of a layer's
     cache or of a whole stack runs, in the loop or around it; the only ops
     of that size are the in-place writes of each step's rows."""
-    over, cap = DECODE_CELLS[arch]
-    cfg = get_config(arch).replace(**over)
-    def put(tree):
-        return jax.tree.map(lambda a: _shape(a.shape, a.dtype, one_chip),
-                            tree)
-    cache = put(jax.eval_shape(lambda: M.init_cache(cfg, 16, cap)))
-    engine = Engine(cfg, None, EngineConfig(slots=16))
-    compiled = engine.decode_fn.lower(
-        put(abstract_params(cfg)), cache,
-        _shape((16, 1), jnp.int32, one_chip),
-        _shape((), jnp.int32, one_chip)).compile()
+    _, _, cache, compiled = decode_step(arch)
     leaves = jax.tree.leaves(cache)
     assert compiled.memory_analysis().alias_size_in_bytes >= sum(
         a.size * a.dtype.itemsize for a in leaves)
@@ -181,3 +196,28 @@ def test_decode_step_updates_the_cache_in_place(one_chip, arch):
                                         "bitcast", "dynamic-update-slice")
            and not (op in ("copy-start", "copy-done") and "S(1)" in line)]
     assert not big, big
+
+
+@pytest.mark.parametrize("arch", sorted(DECODE_CELLS))
+def test_decode_step_converts_no_served_weight(decode_step, arch):
+    """The engine serves every weight the forward reads only in compute
+    dtype already in it: its decode step, compiled for the chip, makes no
+    array the size of such a leaf, or of a layer's slice of one, in
+    another dtype than the leaf's (a float32 tree converts each whole
+    stack there, on every step)."""
+    cfg, params, _, compiled = decode_step(arch)
+    cdt = jnp.dtype(cfg.compute_dtype)
+    hlo_cdt = {"bfloat16": "bf16", "float32": "f32"}[cdt.name]
+    sizes = set()
+    for (path, a), spec in zip(jax.tree_util.tree_leaves_with_path(params),
+                               jax.tree.leaves(schema(cfg))):
+        if spec.compute_read:
+            assert a.dtype == cdt
+            sizes.add(a.size)
+            if path[0].key == "groups":
+                sizes.add(a.size // a.shape[0])
+    converts = [line.strip()[:160]
+                for op, n, line in _scheduled(compiled.as_text())
+                if n in sizes and op not in ("parameter", "get-tuple-element")
+                and re.search(r"= (\w+)\[", line).group(1) != hlo_cdt]
+    assert not converts, converts

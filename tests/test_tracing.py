@@ -169,6 +169,28 @@ def test_engine_spans_one_wave(tmp_path, tracer, monkeypatch):
     assert rows["engine.decode"]["compiles"] >= 1
 
 
+def test_engine_load_span_counts_the_leaves_cast(tmp_path, tracer):
+    """``engine.load`` carries how many leaves the engine cast to the
+    compute dtype and the megabytes of the copies: every matmul leaf of a
+    float32 tree, none of a tree stored in the compute dtype."""
+    from repro.models.schema import schema
+    from repro.serve import Engine, EngineConfig
+    cfg, params = _smoke_model()
+    assert cfg.param_dtype == "float32" and cfg.compute_dtype == "bfloat16"
+    cast = [s for s in jax.tree.leaves(schema(cfg)) if s.compute_read]
+    mb = sum(int(np.prod(s.shape)) for s in cast) * 2 / 1e6
+    bf16 = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+    events = _program(_profiled(tmp_path, lambda: [
+        Engine(cfg, params, EngineConfig(slots=2)),
+        Engine(cfg.replace(param_dtype="bfloat16"), bf16,
+               EngineConfig(slots=2))]))
+    loads = sorted((e for e in events if e[0] == "repro.engine.load"),
+                   key=lambda e: e[1])
+    assert [e[3] for e in loads] == [
+        {"cast_leaves": len(cast), "cast_mb": round(mb, 1)},
+        {"cast_leaves": 0, "cast_mb": 0.0}]
+
+
 def test_engine_dispatches_one_step_past_a_wave_done(tmp_path, tracer):
     """Every row reaches EOS at its k-th new token: the step-by-step loop
     would dispatch k - 1 decode steps; the engine dispatches one more and
